@@ -1,8 +1,9 @@
 """Kernel-level benchmark: fused single-pass vs two-pass quantized LoRA
 apply, and batched vs per-layer adapter quantization.
 
-On this CPU container the Pallas kernels run in interpret mode, so
-wall-times are NOT TPU times; the reported derived metrics are
+On a CPU backend the Pallas kernels run in the interpreter, so wall-times
+(tagged ``(interpret)``) are NOT device times; the reported derived
+metrics are
 
 * kernel-launch counts (fused path must be exactly 1 ``pallas_call``),
 * the HBM-traffic model — packed bytes vs fp16 bytes per adapter apply,
@@ -26,6 +27,8 @@ from repro.core import LoRAQuantConfig, quantize_lora, quantize_lora_stack
 from repro.core.quant import storage_bits
 from repro.kernels.quant_matmul import kernel as _kernel
 from repro.kernels.quant_matmul.ops import SUBLANE, lora_apply_quantized
+
+from .common import timing_label
 
 
 def _decayed_pair(m, n, r, rng, decay=0.4):
@@ -54,17 +57,16 @@ def run(report):
     results = {}
     for name, fused in (("fused", True), ("two_pass", False)):
         _kernel.reset_launch_counts()
-        y = lora_apply_quantized(x, ql, interpret=True, fused=fused)
+        y = lora_apply_quantized(x, ql, fused=fused)
         launches = sum(_kernel.LAUNCH_COUNTS.values())
         err = float(jnp.max(jnp.abs(y - ref)))
         t0 = time.perf_counter()
         for _ in range(3):
-            lora_apply_quantized(x, ql, interpret=True,
-                                 fused=fused).block_until_ready()
+            lora_apply_quantized(x, ql, fused=fused).block_until_ready()
         us = (time.perf_counter() - t0) / 3 * 1e6
         results[name] = dict(launches=launches, err=err, us=us)
         report(f"kernels.{name},lora_apply,pallas_calls={launches},"
-               f"us_per_call={us:.0f}(interpret),maxerr={err:.2e}")
+               f"us_per_call={us:.0f}{timing_label()},maxerr={err:.2e}")
 
     # HBM traffic model (memory-bound decode: bytes == wall time)
     packed_bytes = ql.total_bits() / 8

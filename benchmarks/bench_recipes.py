@@ -10,8 +10,8 @@ accounting. This suite reports, per target,
   the target — the acceptance tolerance),
 * relative reconstruction error ``||ΔW_q - ΔW|| / ||ΔW||`` over a small
   decaying-spectrum adapter set,
-* fused-kernel apply throughput (interpret mode; relative numbers only —
-  wider codes unpack more words per weight).
+* fused-kernel apply throughput (tagged ``(interpret)`` on a CPU backend:
+  relative numbers only — wider codes unpack more bit-planes per weight).
 
 Checks assert the frontier is well-formed: every target within tolerance
 and error strictly decreasing as the budget grows.
@@ -28,6 +28,8 @@ import numpy as np
 
 from repro.core import LoRAQuantConfig, fit_recipe, quantize_lora
 from repro.kernels import lora_apply_quantized
+
+from .common import timing_label
 
 TARGETS = (1.0, 1.5, 2.0, 3.0)
 N_ADAPTERS = 3
@@ -65,16 +67,16 @@ def run(report):
             np.linalg.norm(np.asarray(q.delta_w()) - b @ a)
             / np.linalg.norm(b @ a)
             for q, (b, a) in zip(qs, pairs)]))
-        lora_apply_quantized(x, qs[0], interpret=True)      # warmup / trace
+        lora_apply_quantized(x, qs[0])                      # warmup / trace
         t0 = time.perf_counter()
         for _ in range(APPLY_REPEATS):
-            lora_apply_quantized(x, qs[0], interpret=True).block_until_ready()
+            lora_apply_quantized(x, qs[0]).block_until_ready()
         tok_s = T_TOKENS * APPLY_REPEATS / (time.perf_counter() - t0)
         rows.append((target, rec, achieved, err, tok_s))
         report(f"recipes.frontier,target_{target:g},"
                f"recipe={rec.bits_high}@{rec.rho:.3f},"
                f"avg_bits={achieved:.3f},recon_rel_err={err:.4f},"
-               f"tok_s={tok_s:.1f}(interpret)")
+               f"tok_s={tok_s:.1f}{timing_label()}")
 
     within = all(abs(ach - t) <= 0.25 for t, _, ach, _, _ in rows)
     report(f"recipes.check,budget_within_quarter_bit,"

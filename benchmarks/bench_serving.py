@@ -2,8 +2,9 @@
 reference, the continuous-batching scheduler vs the static batch under
 staggered arrivals, plus cross-adapter bucketed onboarding.
 
-On this CPU container the Pallas kernels run in interpret mode, so tok/s are
-NOT TPU rates; the decision-grade numbers are
+On a CPU backend the Pallas kernels run in the interpreter, so tok/s
+(tagged ``(interpret)``) are NOT device rates; the decision-grade numbers
+are
 
 * **fp-resident LoRA bytes** during decode — packed mode must be 0 (no
   adapter is ever dequantized; the store's LRU stays empty), the segment
@@ -47,6 +48,8 @@ from repro.launch.serve import random_trained_lora
 from repro.models import build_model
 from repro.serving.engine import AdapterStore, MultiLoRAEngine, Request
 from repro.serving.telemetry import Telemetry
+
+from .common import timing_label
 
 N_ADAPTERS = 3
 N_REQUESTS = 6
@@ -179,13 +182,13 @@ def run(report, telemetry=None):
     done_p, tps_p, dt_p = _timed_run(engine, cfg, "packed")
     fp_packed = store.fp_resident_bytes()
     report(f"serving.packed,hetero_batch,requests={N_REQUESTS},"
-           f"adapters={N_ADAPTERS},tok_s={tps_p:.1f}(interpret),"
+           f"adapters={N_ADAPTERS},tok_s={tps_p:.1f}{timing_label()},"
            f"s={dt_p:.2f},fp_resident_bytes={fp_packed}")
 
     done_m, tps_m, dt_m = _timed_run(engine, cfg, "materialize")
     fp_mat = store.fp_resident_bytes()
     report(f"serving.materialize,segment_loop,requests={N_REQUESTS},"
-           f"adapters={N_ADAPTERS},tok_s={tps_m:.1f}(interpret),"
+           f"adapters={N_ADAPTERS},tok_s={tps_m:.1f}{timing_label()},"
            f"s={dt_m:.2f},fp_resident_bytes={fp_mat}")
 
     parity = all(
@@ -217,11 +220,11 @@ def run(report, telemetry=None):
     toks_s = sum(len(r.output) for r in done_s)
     toks_c = sum(len(r.output) for r in done_c)
     report(f"serving.staggered,static_packed,requests={2*STAG_WAVE},"
-           f"rows={STAG_ROWS},tok_s={toks_s/dt_s:.1f}(interpret),"
+           f"rows={STAG_ROWS},tok_s={toks_s/dt_s:.1f}{timing_label()},"
            f"makespan_s={dt_s:.2f},ttft_wave1_s={_ttft(done_s, arr_s, 0):.2f},"
            f"ttft_wave2_s={_ttft(done_s, arr_s, 1):.2f}")
     report(f"serving.staggered,continuous,requests={2*STAG_WAVE},"
-           f"rows={STAG_ROWS},tok_s={toks_c/dt_c:.1f}(interpret),"
+           f"rows={STAG_ROWS},tok_s={toks_c/dt_c:.1f}{timing_label()},"
            f"makespan_s={dt_c:.2f},ttft_wave1_s={_ttft(done_c, arr_c, 0):.2f},"
            f"ttft_wave2_s={_ttft(done_c, arr_c, 1):.2f}")
     same = all(np.array_equal(
@@ -312,7 +315,7 @@ def run(report, telemetry=None):
 
     base = _churn_stats("all_resident")
     report(f"serving.churn,all_resident,adapters={CHURN_ADAPTERS},"
-           f"slots={base['slots']:.0f},tok_s={base['tok_s']:.1f}(interpret),"
+           f"slots={base['slots']:.0f},tok_s={base['tok_s']:.1f}{timing_label()},"
            f"hit_rate={base['hit_rate']:.2f},"
            f"swapins_per_tok={base['swapins_per_tok']:.3f},"
            f"hbm_mb={base['hbm_mb']:.3f}")
@@ -320,7 +323,7 @@ def run(report, telemetry=None):
     for frac in (25, 50, 100):
         r = frac_runs[frac] = _churn_stats(f"slots_{frac}pct")
         report(f"serving.churn,slots_{frac}pct,adapters={CHURN_ADAPTERS},"
-               f"slots={r['slots']:.0f},tok_s={r['tok_s']:.1f}(interpret),"
+               f"slots={r['slots']:.0f},tok_s={r['tok_s']:.1f}{timing_label()},"
                f"hit_rate={r['hit_rate']:.2f},"
                f"swapins_per_tok={r['swapins_per_tok']:.3f},"
                f"evictions={r['evictions']:.0f},hbm_mb={r['hbm_mb']:.3f},"
@@ -401,7 +404,7 @@ def run(report, telemetry=None):
                f"adapters={CHURN_ADAPTERS},"
                f"recipes={mixed_store.stats()['recipes']:.0f},"
                f"pools={after['pools']:.0f},slots={after['slots']:.0f},"
-               f"tok_s={toks/dt:.1f}(interpret),"
+               f"tok_s={toks/dt:.1f}{timing_label()},"
                f"hit_rate={mem['hits']/total if total else 1.0:.2f},"
                f"evictions={mem['evictions']:.0f},"
                f"hbm_mb={after['hbm_slot_mb']:.3f},"

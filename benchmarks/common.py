@@ -31,6 +31,17 @@ BASE_SEED = 0
 TASK_B_SEED = 101
 
 
+def timing_label() -> str:
+    """Tag printed after every time: ``(interpret)`` when the Pallas kernels
+    ran in the interpreter (a CPU backend — not a device time), otherwise
+    the device kind the time was taken on."""
+    from repro.kernels.quant_matmul.kernel import interpret_mode
+
+    if interpret_mode():
+        return "(interpret)"
+    return f"({jax.devices()[0].device_kind})"
+
+
 @functools.lru_cache(maxsize=2)
 def trained_setup(base_steps: int = 250, lora_steps: int = 200,
                   arch: str = "llama3.2-3b"):

@@ -52,6 +52,9 @@ def main(argv=None):
                         "(load in Perfetto / chrome://tracing)")
     args = p.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from . import (
         bench_chaos,
         bench_kernels,

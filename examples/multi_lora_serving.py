@@ -31,8 +31,7 @@ def kernel_demo():
     segs = [0, 1, 2, 1]                      # tile→adapter map
     seg_ids = np.repeat(segs, tile)
     x = jnp.asarray(rng.normal(size=(len(seg_ids), d)).astype(np.float32))
-    y = sgmv_apply(x, qas, qbts, jnp.asarray(segs, jnp.int32), tile_t=tile,
-                   interpret=True)
+    y = sgmv_apply(x, qas, qbts, jnp.asarray(segs, jnp.int32), tile_t=tile)
     err = float(jnp.max(jnp.abs(y - ref_sgmv(x, qas, qbts, seg_ids))))
     print(f"[sgmv] heterogeneous batch of {len(seg_ids)} tokens × "
           f"{n_adapters} adapters in one kernel; maxerr vs oracle {err:.1e}")

@@ -1,7 +1,8 @@
 """Pallas TPU kernels for the perf-critical serving path: fused
 dequantization of packed LoRAQuant factors + skinny matmuls (single-adapter
-and SGMV multi-adapter variants). Validated on CPU via interpret=True; the
-pure-jnp oracle lives in quant_matmul/ref.py."""
+and SGMV multi-adapter variants). They run natively on a TPU backend and
+in the Pallas interpreter elsewhere (``quant_matmul.kernel.interpret_mode``);
+the pure-jnp oracle lives in quant_matmul/ref.py."""
 
 from .quant_matmul import (
     PackedLoRABatch,

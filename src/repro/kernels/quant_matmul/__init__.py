@@ -3,7 +3,6 @@ from .ops import (
     PackedLoRABuckets,
     lora_apply_quantized,
     pack_adapter_layers,
-    quant_matmul_rhs,
     retile_packed,
     sgmv_apply,
     sgmv_apply_buckets,
@@ -17,7 +16,6 @@ __all__ = [
     "PackedLoRABuckets",
     "lora_apply_quantized",
     "pack_adapter_layers",
-    "quant_matmul_rhs",
     "retile_packed",
     "sgmv_apply",
     "sgmv_apply_buckets",

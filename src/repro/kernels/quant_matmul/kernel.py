@@ -5,49 +5,40 @@ TPU adaptation of Punica's CUDA SGMV (DESIGN.md §2): instead of warp-level
 gathers, requests are host-bucketed into contiguous *segments* per adapter;
 the grid walks token tiles and a scalar-prefetched ``tile→adapter`` map
 selects which adapter's packed codes the BlockSpec index_map pulls into
-VMEM. Dequantization (bit-unpack via lane shifts, group-scale expansion via
-broadcast-reshape) happens in VMEM/VREGs; only packed bytes cross HBM→VMEM,
-so adapter bandwidth is AvgBits/16 of the fp16 path — these matmuls are
-memory-bound at decode, so bandwidth is wall-time.
+VMEM. Dequantization (a shift-and-mask per quant group, then that group's
+scale and zero-point) happens in VMEM/VREGs; only packed bytes cross
+HBM→VMEM, so adapter bandwidth is AvgBits/16 of the fp16 path — these
+matmuls are memory-bound at decode, so bandwidth is wall-time.
 
-Layout contract (== ``repro.core.quant`` storage), in brief:
-  codes  (R, G, ceil(g/per)) uint8/uint32 — ``per`` codes per storage word
-         (8/bits for 1/2/4/8-bit in uint8; 10 for 3-bit in uint32),
-         little-endian within the word, padded per *group* to whole words
+Layout contract (what ``ops._kernel_layout`` builds from
+``repro.core.quant`` storage), in brief:
+  codes  (R, C) uint8   — :func:`pack_planes`: bit-plane-major bytes, plane
+         ``p`` of a row is one contiguous run of its codes; 3-bit codes
+         are a 2-bit plus a 1-bit segment
   scale  (R, G) fp32
-  zero   (R, G) int32          — RTN only
-ops.py reshapes codes to (R, G·words_per_group) before the call; R is
-padded to the fp32 sublane multiple (8). The full packing walkthrough —
-bit layouts per width, the rank-padding rules that make heterogeneous-``h``
-adapter stacks uniform, and the VMEM budget math — lives in
+  zero   (R, G) int32   — RTN only
+R is padded to the fp32 sublane multiple (8). The full walkthrough — bit
+layouts per width, the rank-padding rules that make heterogeneous-``h``
+adapter stacks uniform, and the VMEM budget — lives in
 ``docs/packed_format.md``.
 
 Two kernel families:
 
 * **two-pass** (``matmul_rhs`` / ``matmul_out``, ``sgmv_rhs`` / ``sgmv_out``)
   — the reference path: one ``pallas_call`` per factor, the rank-R
-  intermediate ``h`` round-trips through HBM between them, and ``x`` is read
-  from HBM once per sub-LoRA side. Uses the same group-aware unpack as the
-  fused path, so every bit-width the fused kernels serve (incl. 3-bit
-  uint32 packing) has a two-pass reference; pass ``group`` explicitly for
-  3-bit (the dense uint8 widths infer it from the code/scale shapes).
+  intermediate ``h`` round-trips through HBM between them. Uses the same
+  unpack as the fused path, so every bit-width the fused kernels serve has
+  a two-pass reference.
 * **fused single-pass** (``fused_lora`` / ``sgmv_fused``) — ONE
   ``pallas_call`` per layer. Per token tile the kernel unpacks + dequants
-  A-high/A-low tiles in VMEM, accumulates ``h_hi``/``h_lo`` in fp32 VMEM
-  scratch across the K grid axis, and on the last K step dequants
-  B-high/B-low (held resident in VMEM via constant index maps) and emits
-  ``y = h_hi @ B_hi + h_lo @ B_lo`` directly — ``h`` never touches HBM and
-  ``x`` is read exactly once. The group-aware unpack
-  (``_unpack_dequant_grouped``) slices per-group word padding, so 3-bit
-  uint32 packing is supported as well.
+  the A and B factors of both sub-LoRAs in VMEM and emits
+  ``y = (x @ A_hiᵀ) @ B_hi + (x @ A_loᵀ) @ B_lo`` directly — ``h`` never
+  touches HBM and ``x`` is read exactly once.
 
-Fused-path layout/VMEM contract: K tiles must be a multiple of the A-side
-quant group (so per-tile scale blocks are exact — ops.py's ``_pick_tile``
-guarantees it); the full packed B factors and one (Tt, M) output tile stay
-VMEM-resident (≈ 5.5 MB worst case at Tt=128/M=8192 — the full budget
-table is in ``docs/packed_format.md``). For M beyond ~16k lanes the apply
-wrapper (``ops.lora_apply_quantized``) estimates the per-step VMEM and
-auto-falls back to the two-pass path instead of failing at compile time.
+Every kernel holds whole feature rows: a grid step sees the full K of its
+x tile and the full packed factors (a few KB to tens of KB at rank 16), so
+the only tiled axis is tokens. ``ops.lora_apply_quantized`` shrinks the
+token tile when the fused kernel's VMEM estimate would not fit.
 """
 
 from __future__ import annotations
@@ -59,6 +50,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # Trace-time kernel-launch accounting. Every kernel builder below records its
 # name here once per ``pallas_call`` issued (the apply wrappers in ops.py are
@@ -96,40 +88,104 @@ def _record_launch(name: str) -> None:
         sink(name)
 
 
+def interpret_mode() -> bool:
+    """Whether ``pallas_call`` runs in the Pallas interpreter: natively on
+    a TPU backend, interpreted everywhere else (the CPU test runs). Read
+    at trace time by every kernel builder below; nothing falls back from
+    a failed native compile to the interpreter."""
+    return jax.default_backend() != "tpu"
+
+
+def _planes(bits: int):
+    """Byte bit-plane segments ``(width, shift)`` of a ``bits``-wide code:
+    one segment for 1/2/4/8 bits; a 3-bit code is a 2-bit plane plus a
+    1-bit plane, so every width packs into whole bytes."""
+    return ((2, 0), (1, 2)) if bits == 3 else ((bits, 0),)
+
+
+def _plane_lanes(n: int, width: int) -> int:
+    """Bytes per row of a ``width``-bit segment over ``n`` codes: each byte
+    carries ``8 // width`` codes, one per plane."""
+    return -(-n // (8 // width))
+
+
+@functools.partial(jax.jit, static_argnames=("bits",))
+def pack_planes(q, bits: int):
+    """Integer codes ``(R, N)`` → the kernel's packed layout ``(R, C)``
+    uint8 (``docs/packed_format.md`` §2).
+
+    Per bit-plane segment of ``L = ceil(N / per)`` bytes a row, byte ``j``
+    holds codes ``j, L + j, 2L + j, …`` at bits ``[p·width, (p+1)·width)``:
+    plane ``p`` is the contiguous run of codes ``[p·L, (p+1)·L)``. One
+    shift-and-mask per plane unpacks it onto consecutive lanes, so no lane
+    axis is ever split or interleaved, and a row spends exactly ``bits``
+    per code whenever ``per`` divides ``N``."""
+    r, n = q.shape
+    segs = []
+    for width, shift in _planes(bits):
+        per = 8 // width
+        lanes = _plane_lanes(n, width)
+        part = (q.astype(jnp.int32) >> shift) & ((1 << width) - 1)
+        part = jnp.pad(part, ((0, 0), (0, lanes * per - n)))
+        part = part.reshape(r, per, lanes)
+        segs.append(functools.reduce(
+            jnp.bitwise_or, [part[:, p] << (width * p) for p in range(per)]))
+    return jnp.concatenate(segs, axis=1).astype(jnp.uint8)
+
+
 def _infer_group(codes, scale, bits: int, group: Optional[int]) -> int:
-    """Dense uint8 widths carry exactly ``8/bits`` codes per word, so the
-    group size follows from the word/group shape ratio; 3-bit uint32 packing
-    (10 codes/word, per-group padding) must pass ``group`` explicitly."""
+    """A row holds ``bits`` per code, so the group size follows from the
+    code and scale shapes."""
     if group is not None:
         return group
-    if bits == 3:
-        raise ValueError("3-bit packing needs an explicit quant group size")
-    return codes.shape[-1] // scale.shape[-1] * (8 // bits)
+    return codes.shape[-1] * 8 // (bits * scale.shape[-1])
 
 
 def _unpack_dequant_grouped(codes, scale, zero, bits: int, group: int):
-    """Group-aware unpack: codes (R, NG·Wg) → fp32 (R, NG·group).
+    """Unpack + dequantize: codes (R, C) uint8 → fp32 (R, NG·group).
 
-    ``NG`` is the number of quant groups in this tile (= scale.shape[1]) and
-    ``Wg = ceil(group/per)`` the storage words per group. Unpacking happens
-    per group and the per-group word padding is sliced off, which makes this
-    path exact for 3-bit uint32 packing (10 codes/word, 2 bits wasted) as
-    well as the dense uint8 widths.
+    Each bit-plane is one shift-and-mask of its segment; the planes join
+    along lanes into the row's codes in order (3-bit codes add the 1-bit
+    segment above the 2-bit one). Then each of the ``NG`` (=
+    scale.shape[1]) quant groups — a ``group``-lane slice — takes its own
+    scale column. At ``group == 128`` and lane-tile multiples of codes per
+    plane every slice and join is lane-tile aligned.
     """
-    per = 10 if bits == 3 else 8 // bits
-    mask = (1 << bits) - 1
-    r, c = codes.shape
     ng = scale.shape[1]
-    wpg = c // ng
-    w = codes.reshape(r, ng, wpg).astype(jnp.int32)   # ≤30 payload bits: safe
-    planes = [(w >> (bits * i)) & mask for i in range(per)]
-    q = jnp.stack(planes, axis=-1).reshape(r, ng, wpg * per)
-    q = q[:, :, :group].astype(jnp.float32)           # drop per-group pad
-    if zero is None:                                  # binary: {0,1} → ±scale
-        deq = scale[:, :, None] * (q * 2.0 - 1.0)
-    else:
-        deq = scale[:, :, None] * (q - zero.astype(jnp.float32)[:, :, None])
-    return deq.reshape(r, ng * group)
+    n = ng * group
+    w = codes.astype(jnp.int32)
+    q, off = None, 0
+    for width, shift in _planes(bits):
+        lanes = _plane_lanes(n, width)
+        seg = w[:, off:off + lanes]
+        planes = [(seg >> (width * p)) & ((1 << width) - 1)
+                  for p in range(8 // width)]
+        part = jnp.concatenate(planes, axis=1)[:, :n]
+        q = part if q is None else q | (part << shift)
+        off += lanes
+    q = q.astype(jnp.float32)
+    cols = []
+    for gi in range(ng):
+        qg = q[:, gi * group:(gi + 1) * group]
+        s = scale[:, gi:gi + 1]
+        if zero is None:                              # binary: {0,1} → ±scale
+            cols.append(s * (qg * 2.0 - 1.0))
+        else:
+            cols.append(s * (qg - zero[:, gi:gi + 1].astype(jnp.float32)))
+    return cols[0] if ng == 1 else jnp.concatenate(cols, axis=1)
+
+
+def _dot_t(x, w):
+    """``x @ w.T`` in fp32 without materializing the transpose."""
+    return jax.lax.dot_general(x, w, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _tiles(x, tile_t: int):
+    """(T, D) → (T/tile_t, tile_t, D): a token tile is then a block whose
+    last two dims equal the array's, legal for any ``tile_t`` (a decode
+    tile of one row included)."""
+    return x.reshape(x.shape[0] // tile_t, tile_t, x.shape[1])
 
 
 # --------------------------------------------------------------------------
@@ -137,54 +193,37 @@ def _unpack_dequant_grouped(codes, scale, zero, bits: int, group: int):
 # --------------------------------------------------------------------------
 
 def _matmul_rhs_kernel(x_ref, codes_ref, scale_ref, zero_ref, o_ref, *,
-                       bits: int, binary: bool, group: int):
-    nj = pl.program_id(1)
+                       bits: int, binary: bool, group: int, k: int):
     w = _unpack_dequant_grouped(
         codes_ref[...], scale_ref[...],
-        None if binary else zero_ref[...], bits, group)   # (R, Kt)
-    part = jnp.dot(x_ref[...].astype(jnp.float32), w.T,
-                   preferred_element_type=jnp.float32)  # (Tt, R)
-
-    @pl.when(nj == 0)
-    def _():
-        o_ref[...] = part
-
-    @pl.when(nj != 0)
-    def _():
-        o_ref[...] += part
+        None if binary else zero_ref[...], bits, group)   # (R, ≥K)
+    o_ref[...] = _dot_t(x_ref[...].astype(jnp.float32), w[:, :k])
 
 
 def matmul_rhs(x, codes, scale, zero, *, bits: int, binary: bool,
-               group: Optional[int] = None,
-               tile_t: int = 128, tile_k: int = 512, interpret: bool = False):
-    """x (T, K) @ dequant(codes...)ᵀ → (T, R) fp32. K % tile_k == 0 and
-    tile_k % group == 0 required (ops.py guarantees both by construction:
-    K is a d_model-like multiple of 128 and ``_pick_tile`` aligns tiles to
-    quant groups)."""
+               group: Optional[int] = None, tile_t: int = 128):
+    """x (T, K) @ dequant(codes...)ᵀ → (T, R) fp32; T % tile_t == 0. Each
+    grid step holds one token tile and the whole packed factor."""
     t, k = x.shape
     r = codes.shape[0]
     tile_t = min(tile_t, t)
-    tile_k = min(tile_k, k)
     group = _infer_group(codes, scale, bits, group)
-    grid = (t // tile_t, k // tile_k)
-    g_per_tile = scale.shape[1] // grid[1]
-    wpg = codes.shape[1] // scale.shape[1]            # storage words per group
 
     kern = functools.partial(_matmul_rhs_kernel, bits=bits, binary=binary,
-                             group=group)
+                             group=group, k=k)
     _record_launch("matmul_rhs")
     return pl.pallas_call(
         kern,
-        grid=grid,
+        grid=(t // tile_t,),
         in_specs=[
-            pl.BlockSpec((tile_t, tile_k), lambda i, j: (i, j)),
-            pl.BlockSpec((r, g_per_tile * wpg), lambda i, j: (0, j)),
-            pl.BlockSpec((r, g_per_tile), lambda i, j: (0, j)),
-            pl.BlockSpec((r, g_per_tile), lambda i, j: (0, j)),
+            pl.BlockSpec((tile_t, k), lambda i: (i, 0)),
+            pl.BlockSpec(codes.shape, lambda i: (0, 0)),
+            pl.BlockSpec(scale.shape, lambda i: (0, 0)),
+            pl.BlockSpec(zero.shape, lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((tile_t, r), lambda i, j: (i, 0)),
+        out_specs=pl.BlockSpec((tile_t, r), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((t, r), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(x, codes, scale, zero)
 
 
@@ -196,41 +235,36 @@ def _matmul_out_kernel(h_ref, codes_ref, scale_ref, zero_ref, o_ref, *,
                        bits: int, binary: bool, group: int):
     w = _unpack_dequant_grouped(
         codes_ref[...], scale_ref[...],
-        None if binary else zero_ref[...], bits, group)   # (R, Mt)
+        None if binary else zero_ref[...], bits, group)   # (R, Mp)
     o_ref[...] = jnp.dot(h_ref[...].astype(jnp.float32), w,
                          preferred_element_type=jnp.float32)
 
 
 def matmul_out(h, codes, scale, zero, *, bits: int, binary: bool,
-               group: Optional[int] = None,
-               tile_t: int = 128, tile_m: int = 512, interpret: bool = False):
+               group: Optional[int] = None, tile_t: int = 128):
     """h (T, R) @ dequant(codes: (R, M))ᵀ-free → (T, Mp) fp32, where
     ``Mp = n_groups · group`` (== M except when the last quant group is
-    padded, e.g. under 3-bit packing — callers slice ``[:, :m]``)."""
+    padded — callers slice ``[:, :m]``)."""
     t, r = h.shape
     group = _infer_group(codes, scale, bits, group)
     mp = scale.shape[1] * group
     tile_t = min(tile_t, t)
-    tile_m = min(tile_m, mp)
-    grid = (t // tile_t, mp // tile_m)
-    g_per_tile = scale.shape[1] // grid[1]
-    wpg = codes.shape[1] // scale.shape[1]
 
     kern = functools.partial(_matmul_out_kernel, bits=bits, binary=binary,
                              group=group)
     _record_launch("matmul_out")
     return pl.pallas_call(
         kern,
-        grid=grid,
+        grid=(t // tile_t,),
         in_specs=[
-            pl.BlockSpec((tile_t, r), lambda i, j: (i, 0)),
-            pl.BlockSpec((r, g_per_tile * wpg), lambda i, j: (0, j)),
-            pl.BlockSpec((r, g_per_tile), lambda i, j: (0, j)),
-            pl.BlockSpec((r, g_per_tile), lambda i, j: (0, j)),
+            pl.BlockSpec((tile_t, r), lambda i: (i, 0)),
+            pl.BlockSpec(codes.shape, lambda i: (0, 0)),
+            pl.BlockSpec(scale.shape, lambda i: (0, 0)),
+            pl.BlockSpec(zero.shape, lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((tile_t, tile_m), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((tile_t, mp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((t, mp), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(h, codes, scale, zero)
 
 
@@ -238,18 +272,36 @@ def matmul_out(h, codes, scale, zero, *, bits: int, binary: bool,
 # SGMV: per-token-tile adapter selection via scalar prefetch
 # --------------------------------------------------------------------------
 
+def _adapter_specs(codes, scale, zero):
+    """BlockSpecs gathering one adapter's packed side (the scalar-prefetched
+    segment map picks which) from an ``(NA, R, ·)`` stack."""
+    return [pl.BlockSpec((None,) + a.shape[1:],
+                         lambda i, seg: (seg[i], 0, 0))
+            for a in (codes, scale, zero)]
+
+
+def _token_spec(tile_t: int, d: int):
+    return pl.BlockSpec((None, tile_t, d), lambda i, seg: (i, 0, 0))
+
+
+def _sgmv_grid(n_tiles: int, in_specs, out_specs):
+    """One grid step per token tile; the segment map is scalar-prefetched
+    so the index maps can pick each tile's adapter."""
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(n_tiles,),
+        in_specs=in_specs, out_specs=out_specs)
+
+
 def _sgmv_kernel(seg_map_ref, x_ref, codes_ref, scale_ref, zero_ref, o_ref, *,
                  bits: int, binary: bool, group: int, k: int):
     w = _unpack_dequant_grouped(
-        codes_ref[0], scale_ref[0],
-        None if binary else zero_ref[0], bits, group)  # (R, ≥K)
-    o_ref[...] = jnp.dot(x_ref[...].astype(jnp.float32), w[:, :k].T,
-                         preferred_element_type=jnp.float32)
+        codes_ref[...], scale_ref[...],
+        None if binary else zero_ref[...], bits, group)  # (R, ≥K)
+    o_ref[...] = _dot_t(x_ref[...].astype(jnp.float32), w[:, :k])
 
 
 def sgmv_rhs(x, codes, scale, zero, seg_map, *, bits: int, binary: bool,
-             group: Optional[int] = None,
-             tile_t: int = 8, interpret: bool = False):
+             group: Optional[int] = None, tile_t: int = 8):
     """Segment-gathered h = x @ Aᵀ with per-tile adapters.
 
     x (T, K); codes (NA, R, words); seg_map (T/tile_t,) int32 — adapter id of
@@ -258,95 +310,68 @@ def sgmv_rhs(x, codes, scale, zero, seg_map, *, bits: int, binary: bool,
     t, k = x.shape
     na, r, _ = codes.shape
     group = _infer_group(codes, scale, bits, group)
-    grid = (t // tile_t,)
 
     kern = functools.partial(_sgmv_kernel, bits=bits, binary=binary,
                              group=group, k=k)
-    grid_spec = pl.GridSpec(
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile_t, k), lambda i, seg: (i, 0)),
-            pl.BlockSpec((1, r, codes.shape[2]), lambda i, seg: (seg[i], 0, 0)),
-            pl.BlockSpec((1, r, scale.shape[2]), lambda i, seg: (seg[i], 0, 0)),
-            pl.BlockSpec((1, r, zero.shape[2]), lambda i, seg: (seg[i], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((tile_t, r), lambda i, seg: (i, 0)),
-    )
+    grid_spec = _sgmv_grid(
+        t // tile_t,
+        [_token_spec(tile_t, k), *_adapter_specs(codes, scale, zero)],
+        _token_spec(tile_t, r))
     _record_launch("sgmv_rhs")
     return pl.pallas_call(
         kern,
-        grid_spec=pltpu_grid(grid_spec, num_scalar_prefetch=1),
-        out_shape=jax.ShapeDtypeStruct((t, r), jnp.float32),
-        interpret=interpret,
-    )(seg_map, x, codes, scale, zero)
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((t // tile_t, tile_t, r), jnp.float32),
+        interpret=interpret_mode(),
+    )(seg_map, _tiles(x, tile_t), codes, scale, zero).reshape(t, r)
 
 
 def _sgmv_out_kernel(seg_map_ref, h_ref, codes_ref, scale_ref, zero_ref,
                      o_ref, *, bits: int, binary: bool, group: int, m: int):
     w = _unpack_dequant_grouped(
-        codes_ref[0], scale_ref[0],
-        None if binary else zero_ref[0], bits, group)  # (R, ≥M)
+        codes_ref[...], scale_ref[...],
+        None if binary else zero_ref[...], bits, group)  # (R, ≥M)
     o_ref[...] = jnp.dot(h_ref[...].astype(jnp.float32), w[:, :m],
                          preferred_element_type=jnp.float32)
 
 
 def sgmv_out(h, codes, scale, zero, seg_map, *, bits: int, binary: bool,
              group: Optional[int] = None, m: Optional[int] = None,
-             tile_t: int = 8, interpret: bool = False):
+             tile_t: int = 8):
     """Segment-gathered y = h @ dequant(Bᵀ) with per-tile adapters.
 
     h (T, R); codes (NA, R, words); seg_map (T/tile_t,). ``m`` overrides the
     output width when the last quant group of B is padded."""
     t, r = h.shape
-    na = codes.shape[0]
     group = _infer_group(codes, scale, bits, group)
     if m is None:
         m = scale.shape[2] * group
-    grid = (t // tile_t,)
 
     kern = functools.partial(_sgmv_out_kernel, bits=bits, binary=binary,
                              group=group, m=m)
-    grid_spec = pl.GridSpec(
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile_t, r), lambda i, seg: (i, 0)),
-            pl.BlockSpec((1, r, codes.shape[2]), lambda i, seg: (seg[i], 0, 0)),
-            pl.BlockSpec((1, r, scale.shape[2]), lambda i, seg: (seg[i], 0, 0)),
-            pl.BlockSpec((1, r, zero.shape[2]), lambda i, seg: (seg[i], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((tile_t, m), lambda i, seg: (i, 0)),
-    )
+    grid_spec = _sgmv_grid(
+        t // tile_t,
+        [_token_spec(tile_t, r), *_adapter_specs(codes, scale, zero)],
+        _token_spec(tile_t, m))
     _record_launch("sgmv_out")
     return pl.pallas_call(
         kern,
-        grid_spec=pltpu_grid(grid_spec, num_scalar_prefetch=1),
-        out_shape=jax.ShapeDtypeStruct((t, m), jnp.float32),
-        interpret=interpret,
-    )(seg_map, h, codes, scale, zero)
-
-
-def pltpu_grid(grid_spec, num_scalar_prefetch: int, scratch_shapes=()):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=num_scalar_prefetch,
-        grid=grid_spec.grid,
-        in_specs=grid_spec.in_specs,
-        out_specs=grid_spec.out_specs,
-        scratch_shapes=tuple(scratch_shapes),
-    )
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((t // tile_t, tile_t, m), jnp.float32),
+        interpret=interpret_mode(),
+    )(seg_map, _tiles(h, tile_t), codes, scale, zero).reshape(t, m)
 
 
 # --------------------------------------------------------------------------
 # fused single-pass apply: y = (x @ Ahiᵀ) @ Bhi + (x @ Aloᵀ) @ Blo
-# in ONE pallas_call — h_hi/h_lo live in VMEM scratch, never in HBM.
+# in ONE pallas_call — h_hi/h_lo never leave VMEM.
 # --------------------------------------------------------------------------
 
 QuantSide = tuple  # (codes (R, C), scale (R, G), zero (R, G))
 
 
 def fused_lora(
-    x,                               # (T, K) — T % tile_t == 0, K % tile_k == 0
+    x,                               # (T, K) — T % tile_t == 0
     a_hi: QuantSide, b_hi: QuantSide,
     a_lo: Optional[QuantSide] = None, b_lo: Optional[QuantSide] = None,
     *,
@@ -355,123 +380,54 @@ def fused_lora(
     bits_lo: int = 1, binary_lo: bool = True,
     group_ah: int, group_bh: int,
     group_al: int = 0, group_bl: int = 0,
-    tile_t: int = 128, tile_k: int = 512,
-    interpret: bool = False,
+    tile_t: int = 128,
 ):
     """Single-pass fused quantized LoRA apply (see module docstring).
 
-    Grid is (T/tile_t, K/tile_k); the K axis is innermost, so the fp32
-    ``h_hi``/``h_lo`` scratch accumulators are filled across K steps and
-    consumed on the last step, where the VMEM-resident packed B factors are
-    dequantized and the (tile_t, M) output tile is emitted.
+    The grid walks token tiles; every step holds one ``(tile_t, K)`` x tile
+    and the whole packed A and B factors (constant index maps: fetched
+    once), dequantizes them in VMEM, and emits the ``(tile_t, M)`` output
+    tile from ``h = x @ Aᵀ`` without ``h`` touching HBM.
     """
-    from jax.experimental.pallas import tpu as pltpu
-
     t, k = x.shape
     has_low = a_lo is not None
-    r_hi = a_hi[0].shape[0]
-    r_lo = a_lo[0].shape[0] if has_low else 0
-    grid = (t // tile_t, k // tile_k)
-    nj = grid[1]
-
-    ga_tile = a_hi[1].shape[1] // nj             # A-side groups per K tile
-    wpg_ah = a_hi[0].shape[1] // a_hi[1].shape[1]
-    if has_low:
-        gal_tile = a_lo[1].shape[1] // nj
-        wpg_al = a_lo[0].shape[1] // a_lo[1].shape[1]
 
     def kernel(*refs):
         if has_low:
             (x_ref, ahc, ahs, ahz, alc, als, alz,
-             bhc, bhs, bhz, blc, bls, blz, o_ref, hhi_ref, hlo_ref) = refs
+             bhc, bhs, bhz, blc, bls, blz, o_ref) = refs
         else:
-            (x_ref, ahc, ahs, ahz, bhc, bhs, bhz, o_ref, hhi_ref) = refs
-        j = pl.program_id(1)
+            (x_ref, ahc, ahs, ahz, bhc, bhs, bhz, o_ref) = refs
         xf = x_ref[...].astype(jnp.float32)
 
-        wa = _unpack_dequant_grouped(
-            ahc[...], ahs[...], None if binary_hi else ahz[...],
-            bits_hi, group_ah)                    # (R_hi, Kt)
-        part = jnp.dot(xf, wa.T, preferred_element_type=jnp.float32)
-
-        @pl.when(j == 0)
-        def _():
-            hhi_ref[...] = part
-
-        @pl.when(j != 0)
-        def _():
-            hhi_ref[...] += part
-
-        if has_low:
-            wal = _unpack_dequant_grouped(
-                alc[...], als[...], None if binary_lo else alz[...],
-                bits_lo, group_al)                # (R_lo, Kt)
-            part_lo = jnp.dot(xf, wal.T, preferred_element_type=jnp.float32)
-
-            @pl.when(j == 0)
-            def _():
-                hlo_ref[...] = part_lo
-
-            @pl.when(j != 0)
-            def _():
-                hlo_ref[...] += part_lo
-
-        @pl.when(j == nj - 1)
-        def _():
+        def apply(ac, as_, az, bc, bs, bz, bits, binary, ga, gb):
+            wa = _unpack_dequant_grouped(
+                ac[...], as_[...], None if binary else az[...], bits, ga)
+            h = _dot_t(xf, wa[:, :k])                       # (Tt, R)
             wb = _unpack_dequant_grouped(
-                bhc[...], bhs[...], None if binary_hi else bhz[...],
-                bits_hi, group_bh)                # (R_hi, M)
-            acc = jnp.dot(hhi_ref[...], wb, preferred_element_type=jnp.float32)
-            if has_low:
-                wbl = _unpack_dequant_grouped(
-                    blc[...], bls[...], None if binary_lo else blz[...],
-                    bits_lo, group_bl)            # (R_lo, M)
-                acc += jnp.dot(hlo_ref[...], wbl,
-                               preferred_element_type=jnp.float32)
-            o_ref[...] = acc
+                bc[...], bs[...], None if binary else bz[...], bits, gb)
+            return jnp.dot(h, wb[:, :m], preferred_element_type=jnp.float32)
 
-    def _a_specs(r, g_tile, wpg):
-        return [
-            pl.BlockSpec((r, g_tile * wpg), lambda i, j: (0, j)),
-            pl.BlockSpec((r, g_tile), lambda i, j: (0, j)),
-            pl.BlockSpec((r, g_tile), lambda i, j: (0, j)),
-        ]
+        acc = apply(ahc, ahs, ahz, bhc, bhs, bhz, bits_hi, binary_hi,
+                    group_ah, group_bh)
+        if has_low:
+            acc += apply(alc, als, alz, blc, bls, blz, bits_lo, binary_lo,
+                         group_al, group_bl)
+        o_ref[...] = acc
 
-    def _b_specs(side):
-        codes, scale, _ = side
-        r, gm = scale.shape
-        return [
-            pl.BlockSpec((r, codes.shape[1]), lambda i, j: (0, 0)),
-            pl.BlockSpec((r, gm), lambda i, j: (0, 0)),
-            pl.BlockSpec((r, gm), lambda i, j: (0, 0)),
-        ]
-
-    in_specs = [pl.BlockSpec((tile_t, tile_k), lambda i, j: (i, j))]
-    in_specs += _a_specs(r_hi, ga_tile, wpg_ah)
-    operands = [x, *a_hi]
-    if has_low:
-        in_specs += _a_specs(r_lo, gal_tile, wpg_al)
-        operands += [*a_lo]
-    in_specs += _b_specs(b_hi)
-    operands += [*b_hi]
-    if has_low:
-        in_specs += _b_specs(b_lo)
-        operands += [*b_lo]
-
-    scratch = [pltpu.VMEM((tile_t, r_hi), jnp.float32)]
-    if has_low:
-        scratch.append(pltpu.VMEM((tile_t, r_lo), jnp.float32))
-
+    sides = [*a_hi] + ([*a_lo] if has_low else []) + [*b_hi]
+    sides += [*b_lo] if has_low else []
+    in_specs = [pl.BlockSpec((tile_t, k), lambda i: (i, 0))]
+    in_specs += [pl.BlockSpec(a.shape, lambda i: (0, 0)) for a in sides]
     _record_launch("fused_lora")
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(t // tile_t,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((tile_t, m), lambda i, j: (i, 0)),
+        out_specs=pl.BlockSpec((tile_t, m), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((t, m), jnp.float32),
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(*operands)
+        interpret=interpret_mode(),
+    )(x, *sides)
 
 
 # --------------------------------------------------------------------------
@@ -486,7 +442,7 @@ def sgmv_fused(
     bits_lo: int = 1, binary_lo: bool = True,
     group_al: int = 0, group_bl: int = 0,
     m: Optional[int] = None,
-    tile_t: int = 8, interpret: bool = False,
+    tile_t: int = 8,
 ):
     """Single-kernel heterogeneous multi-adapter apply.
 
@@ -494,7 +450,9 @@ def sgmv_fused(
     int32 adapter id per token tile. The scalar-prefetched ``seg_map`` drives
     the BlockSpec index maps of BOTH factor sides, so each grid step DMAs one
     adapter's packed A and B and computes ``y = (x @ Aᵀ) @ B`` entirely in
-    VMEM — the (tile_t, R) ``h`` exists only in registers/VREGs.
+    VMEM — the (tile_t, R) ``h`` exists only in registers/VREGs. Token
+    tiles are blocks of a ``(T/tile_t, tile_t, K)`` view, so ``tile_t = 1``
+    (decode: one row per sequence) is as legal a block as ``tile_t = 8``.
 
     ``a_lo``/``b_lo`` (each an (NA, R_lo, ·) codes/scale/zero triple) add the
     LoRAQuant binary sub-LoRA in the SAME launch:
@@ -510,12 +468,9 @@ def sgmv_fused(
     off in-kernel before the output dot.
     """
     t, k = x.shape
-    na, r, _ = a_codes.shape
     has_low = a_lo is not None
     if m is None:
         m = b_scale.shape[2] * group_b
-    r_lo = a_lo[0].shape[1] if has_low else 0
-    grid = (t // tile_t,)
 
     def kernel(*refs):
         if has_low:
@@ -525,49 +480,36 @@ def sgmv_fused(
             (seg_map_ref, x_ref, ac, as_, az, bc, bs, bz, o_ref) = refs
         xf = x_ref[...].astype(jnp.float32)
         wa = _unpack_dequant_grouped(
-            ac[0], as_[0], None if binary_a else az[0], bits_a, group_a)
-        h = jnp.dot(xf, wa[:, :k].T,
-                    preferred_element_type=jnp.float32)     # (Tt, R)
+            ac[...], as_[...], None if binary_a else az[...], bits_a, group_a)
+        h = _dot_t(xf, wa[:, :k])                           # (Tt, R)
         wb = _unpack_dequant_grouped(
-            bc[0], bs[0], None if binary_b else bz[0], bits_b, group_b)
+            bc[...], bs[...], None if binary_b else bz[...], bits_b, group_b)
         acc = jnp.dot(h, wb[:, :m], preferred_element_type=jnp.float32)
         if has_low:
             wal = _unpack_dequant_grouped(
-                alc[0], als[0], None if binary_lo else alz[0],
+                alc[...], als[...], None if binary_lo else alz[...],
                 bits_lo, group_al)
-            h_lo = jnp.dot(xf, wal[:, :k].T,
-                           preferred_element_type=jnp.float32)  # (Tt, R_lo)
+            h_lo = _dot_t(xf, wal[:, :k])                   # (Tt, R_lo)
             wbl = _unpack_dequant_grouped(
-                blc[0], bls[0], None if binary_lo else blz[0],
+                blc[...], bls[...], None if binary_lo else blz[...],
                 bits_lo, group_bl)
             acc += jnp.dot(h_lo, wbl[:, :m], preferred_element_type=jnp.float32)
         o_ref[...] = acc
 
-    def _adapter_specs(codes, scale, zero, rr):
-        return [
-            pl.BlockSpec((1, rr, codes.shape[2]), lambda i, seg: (seg[i], 0, 0)),
-            pl.BlockSpec((1, rr, scale.shape[2]), lambda i, seg: (seg[i], 0, 0)),
-            pl.BlockSpec((1, rr, zero.shape[2]), lambda i, seg: (seg[i], 0, 0)),
-        ]
-
-    in_specs = [pl.BlockSpec((tile_t, k), lambda i, seg: (i, 0))]
-    in_specs += _adapter_specs(a_codes, a_scale, a_zero, r)
-    in_specs += _adapter_specs(b_codes, b_scale, b_zero, r)
-    operands = [x, a_codes, a_scale, a_zero, b_codes, b_scale, b_zero]
+    in_specs = [_token_spec(tile_t, k)]
+    in_specs += _adapter_specs(a_codes, a_scale, a_zero)
+    in_specs += _adapter_specs(b_codes, b_scale, b_zero)
+    operands = [a_codes, a_scale, a_zero, b_codes, b_scale, b_zero]
     if has_low:
-        in_specs += _adapter_specs(*a_lo, r_lo)
-        in_specs += _adapter_specs(*b_lo, r_lo)
+        in_specs += _adapter_specs(*a_lo) + _adapter_specs(*b_lo)
         operands += [*a_lo, *b_lo]
 
-    grid_spec = pl.GridSpec(
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((tile_t, m), lambda i, seg: (i, 0)),
-    )
+    grid_spec = _sgmv_grid(t // tile_t, in_specs,
+                           _token_spec(tile_t, m))
     _record_launch("sgmv_fused")
     return pl.pallas_call(
         kernel,
-        grid_spec=pltpu_grid(grid_spec, num_scalar_prefetch=1),
-        out_shape=jax.ShapeDtypeStruct((t, m), jnp.float32),
-        interpret=interpret,
-    )(seg_map, *operands)
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((t // tile_t, tile_t, m), jnp.float32),
+        interpret=interpret_mode(),
+    )(seg_map, _tiles(x, tile_t), *operands).reshape(t, m)

@@ -6,8 +6,9 @@ multiple) and provide the full sub-LoRA application:
 
     lora_apply_quantized(x, qlora) ≈ x @ qlora.delta_w().T
 
-``interpret=True`` everywhere in this container (CPU validation of the TPU
-kernel body); on real TPUs pass ``interpret=False``.
+Whether the kernels run natively or in the Pallas interpreter follows the
+JAX backend (``kernel.interpret_mode``): native on a TPU, interpreted on
+the CPU test runs.
 """
 
 from __future__ import annotations
@@ -18,78 +19,56 @@ from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core.loraquant import QuantizedLoRA
-from repro.core.quant import QuantizedTensor
+from repro.core.quant import QuantizedTensor, unpack_codes
 
-from .kernel import fused_lora, matmul_out, matmul_rhs, sgmv_fused, sgmv_rhs
+from .kernel import (
+    fused_lora,
+    matmul_out,
+    matmul_rhs,
+    pack_planes,
+    sgmv_fused,
+    sgmv_rhs,
+)
 
 SUBLANE = 8
-TILE_CAP = 2048          # max feature-tile lanes considered per kernel step
 
-# VMEM the fused single-pass kernel may budget for per grid step. Real TPU
-# cores have ~16 MB of VMEM; leaving headroom for double-buffered DMA and
-# the compiler's own scratch, the fused path auto-falls back to two-pass
-# when its estimate exceeds this (see _fused_vmem_estimate).
+# VMEM the fused single-pass kernel may budget for per grid step (a TPU
+# v5e core has 16 MiB of scoped VMEM by default; the rest is headroom for
+# the compiler's own scratch). lora_apply_quantized halves the token tile
+# until _fused_vmem_estimate fits.
 FUSED_VMEM_BUDGET = 12 << 20
 
 
-def _fused_vmem_estimate(qlora: QuantizedLoRA, tile_t: int, tile_k: int) -> int:
-    """Bytes the fused kernel keeps VMEM-resident in one grid step: the x
-    and A-side K tiles, the FULL packed B factors plus their fp32
-    dequantized forms (B is held resident via constant index maps and
-    dequantized whole on the last K step), the (tile_t, M) output tile, and
-    the fp32 h scratch accumulators. Deliberately an upper-bound-ish
-    estimate — crossing it means "don't try to compile this fused"."""
+def _fused_vmem_estimate(qlora: QuantizedLoRA, tile_t: int) -> int:
+    """Upper-bound bytes the fused kernel keeps in VMEM in one grid step:
+    the double-buffered ``(tile_t, K)`` x tile and ``(tile_t, M)`` output
+    tile plus the fp32 copy of x, and per factor its packed form and its
+    fp32 dequantized form."""
     k = qlora.a_high.orig_shape[1]
     m = qlora.b_high.orig_shape[0]
-    a_sides = [qlora.a_high] + ([qlora.a_low] if qlora.a_low is not None else [])
-    b_sides = [qlora.b_high] + ([qlora.b_low] if qlora.b_low is not None else [])
-
-    def packed_bytes(q):
-        return (q.codes.size * q.codes.dtype.itemsize
-                + q.scale.size * 4 + q.zero.size * 4)
-
-    est = tile_t * tile_k * 4 + tile_t * m * 4        # x tile + output tile
-    for q in a_sides:
-        est += packed_bytes(q) * tile_k // max(k, 1)  # A-side K tile
-        est += tile_t * q.scale.shape[0] * 4          # h scratch row
-    for q in b_sides:
-        est += packed_bytes(q)                        # full packed B
-        est += q.scale.shape[0] * m * 4               # dequantized B (fp32)
+    est = tile_t * k * 4 * 3 + tile_t * m * 4 * 2
+    for q in (qlora.a_high, qlora.b_high, qlora.a_low, qlora.b_low):
+        if q is not None:
+            est += (q.codes.size * q.codes.dtype.itemsize
+                    + q.scale.size * 8 + q.scale.shape[0] * (k + m) * 4)
     return est
 
 
-def _pick_tile(n: int, group: int, cap: int = TILE_CAP) -> int:
-    """Largest tile ≤ cap that divides ``n`` and is a multiple of the quant
-    group size ``group`` (so per-tile scale blocks are exact).
-
-    Replaces the old ``while n % t: t //= 2`` + ``max(t, 128)`` logic, which
-    could *reinstate* a non-dividing tile after the halving loop (e.g.
-    K = 2112 with 64-wide groups: the loop lands on 64, ``max(64, 128)``
-    bumps it to 128, and 2112 % 128 != 0 silently drops the K tail).
-    """
-    if n <= cap:
-        return n
-    if group <= 0 or n % group:
-        raise ValueError(f"feature dim {n} is not a multiple of group {group}")
-    ng = n // group
-    for t in range(min(cap // group, ng), 0, -1):
-        if ng % t == 0:
-            return t * group
-    return group
-
-
 def _kernel_layout(q: QuantizedTensor, pad_r: Optional[int] = None):
-    """QuantizedTensor → (codes (R, K/per), scale (R, G), zero (R, G)).
+    """QuantizedTensor → (codes (R, C) uint8, scale (R, G), zero (R, G)).
 
     Works for row-grouped (axis=1) tensors; column-grouped B factors
-    (axis=0) are the same buffers viewed as Bᵀ. R is zero-padded to the
-    sublane multiple (zero scale rows dequantize to 0 — no effect).
+    (axis=0) are the same buffers viewed as Bᵀ. The stored codes are
+    re-packed into the kernel's bit-plane-major layout
+    (:func:`~repro.kernels.quant_matmul.kernel.pack_planes`): exactly
+    ``bits`` per code, as stored. R is zero-padded to the sublane multiple
+    (zero scale rows dequantize to 0 — no effect).
     """
-    r = q.scale.shape[0]
-    codes = q.codes.reshape(r, -1)
+    r, ng = q.scale.shape
+    codes = unpack_codes(q.codes, q.bits, q.group_size)
+    codes = pack_planes(codes.reshape(r, ng * q.group_size), q.bits)
     scale = q.scale
     zero = q.zero
     rp = pad_r or (-(-r // SUBLANE) * SUBLANE)
@@ -108,21 +87,11 @@ def _pad_tokens(x, tile_t):
     return x, t
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "tile_t", "tile_k"))
-def quant_matmul_rhs(x, codes, scale, zero, *, bits, binary, interpret=True,
-                     tile_t=128, tile_k=512):
-    return matmul_rhs(x, codes, scale, zero, bits=bits, binary=binary,
-                      tile_t=tile_t, tile_k=tile_k, interpret=interpret)
-
-
-def _side(x, q: QuantizedTensor, interpret, tile_t):
+def _side(x, q: QuantizedTensor, tile_t):
     codes, scale, zero, r = _kernel_layout(q)
-    binary = q.mode == "binary"
-    k = x.shape[1]
-    tile_k = _pick_tile(k, q.group_size)
-    h = matmul_rhs(x, codes, scale, zero, bits=q.bits, binary=binary,
-                   group=q.group_size, tile_t=tile_t, tile_k=tile_k,
-                   interpret=interpret)
+    h = matmul_rhs(x, codes, scale, zero, bits=q.bits,
+                   binary=q.mode == "binary", group=q.group_size,
+                   tile_t=tile_t)
     return h, r
 
 
@@ -132,41 +101,34 @@ def _quant_m(q: QuantizedTensor) -> int:
     return q.orig_shape[0] if q.axis == 0 else q.orig_shape[1]
 
 
-def _out_side(h, q: QuantizedTensor, interpret, tile_t):
+def _out_side(h, q: QuantizedTensor, tile_t):
     codes, scale, zero, r = _kernel_layout(q)
     if h.shape[1] != codes.shape[0]:
         h = jnp.pad(h, ((0, 0), (0, codes.shape[0] - h.shape[1])))
-    binary = q.mode == "binary"
-    mp = scale.shape[1] * q.group_size     # group-padded width (== M unless
-    tile_m = _pick_tile(mp, q.group_size)  # the last group is padded)
-    y = matmul_out(h, codes, scale, zero, bits=q.bits, binary=binary,
-                   group=q.group_size, tile_t=tile_t, tile_m=tile_m,
-                   interpret=interpret)
-    return y[:, : _quant_m(q)]
+    y = matmul_out(h, codes, scale, zero, bits=q.bits,
+                   binary=q.mode == "binary", group=q.group_size,
+                   tile_t=tile_t)
+    return y[:, : _quant_m(q)]        # drop a padded last group's columns
 
 
-def _fused_apply(x, qlora: QuantizedLoRA, interpret, tile_t) -> jax.Array:
+def _fused_apply(x, qlora: QuantizedLoRA, tile_t) -> jax.Array:
     """Single-``pallas_call`` application of both sub-LoRAs (kernel.fused_lora)."""
     ah = qlora.a_high
     bh = qlora.b_high
     ahc, ahs, ahz, _ = _kernel_layout(ah)
     bhc, bhs, bhz, _ = _kernel_layout(bh)
-    k = x.shape[1]
     m = bh.orig_shape[0]              # B is (M, R) column-grouped
-    tile_k = _pick_tile(k, ah.group_size)
     kwargs = dict(
         m=m,
         bits_hi=ah.bits, binary_hi=ah.mode == "binary",
         group_ah=ah.group_size, group_bh=bh.group_size,
-        tile_t=tile_t, tile_k=tile_k, interpret=interpret,
+        tile_t=tile_t,
     )
     a_lo = b_lo = None
     if qlora.a_low is not None:
         al, bl = qlora.a_low, qlora.b_low
         alc, als, alz, _ = _kernel_layout(al)
         blc, bls, blz, _ = _kernel_layout(bl)
-        if al.group_size != ah.group_size:
-            raise ValueError("fused path requires matching hi/lo A groups")
         a_lo = (alc, als, alz)
         b_lo = (blc, bls, blz)
         kwargs.update(bits_lo=al.bits, binary_lo=al.mode == "binary",
@@ -180,10 +142,8 @@ def lora_apply_quantized(
     qlora: QuantizedLoRA,
     *,
     scaling: float = 1.0,
-    interpret: bool = True,
     tile_t: int = 128,
     fused: bool = True,
-    vmem_budget: Optional[int] = None,
 ) -> jax.Array:
     """Packed-LoRA application: high (RTN) + low (binary) sub-LoRAs.
 
@@ -191,31 +151,27 @@ def lora_apply_quantized(
     consumed as their transposed row-grouped buffers directly — zero-copy).
 
     ``fused=True`` (default) issues exactly ONE ``pallas_call``: the (T, R)
-    intermediates stay in VMEM scratch and ``x`` crosses HBM once. Because
-    the fused kernel holds one (tile_t, M) output tile plus the full packed
-    B factors in VMEM, very wide outputs can exceed the per-step VMEM
-    budget — when :func:`_fused_vmem_estimate` crosses ``vmem_budget``
-    (default :data:`FUSED_VMEM_BUDGET`) the call silently degrades to the
-    two-pass path instead of failing at compile time. ``fused=False`` forces
-    the two-pass reference (up to four ``pallas_call``s, ``h`` round-trips
-    through HBM), which covers every bit-width the fused path does (incl.
-    3-bit uint32 packing).
+    intermediates stay in VMEM and ``x`` crosses HBM once. The kernel holds
+    whole ``(tile_t, K)`` and ``(tile_t, M)`` tiles, so the token tile is
+    halved (down to 8 rows) until :func:`_fused_vmem_estimate` fits
+    :data:`FUSED_VMEM_BUDGET`. ``fused=False`` runs the two-pass reference
+    (up to four ``pallas_call``s, ``h`` round-trips through HBM), which
+    covers every bit-width the fused path does.
     """
+    if fused:
+        while tile_t > 8 and _fused_vmem_estimate(qlora, tile_t) > \
+                FUSED_VMEM_BUDGET:
+            tile_t //= 2
     xp, t = _pad_tokens(x, min(tile_t, max(x.shape[0], 1)))
     tt = min(tile_t, xp.shape[0])
     if fused:
-        budget = FUSED_VMEM_BUDGET if vmem_budget is None else vmem_budget
-        tk = _pick_tile(x.shape[1], qlora.a_high.group_size)
-        if _fused_vmem_estimate(qlora, tt, tk) > budget:
-            fused = False                 # large-M guard: two-pass fallback
-    if fused:
-        y = _fused_apply(xp, qlora, interpret, tt)
+        y = _fused_apply(xp, qlora, tt)
         return (scaling * y[:t]).astype(x.dtype)
-    h_hi, _ = _side(xp, qlora.a_high, interpret, tt)
-    y = _out_side(h_hi, qlora.b_high, interpret, tt)
+    h_hi, _ = _side(xp, qlora.a_high, tt)
+    y = _out_side(h_hi, qlora.b_high, tt)
     if qlora.a_low is not None:
-        h_lo, _ = _side(xp, qlora.a_low, interpret, tt)
-        y = y + _out_side(h_lo, qlora.b_low, interpret, tt)
+        h_lo, _ = _side(xp, qlora.a_low, tt)
+        y = y + _out_side(h_lo, qlora.b_low, tt)
     return (scaling * y[:t]).astype(x.dtype)
 
 
@@ -241,7 +197,6 @@ def sgmv_apply(
     *,
     scaling: float = 1.0,
     tile_t: int = 8,
-    interpret: bool = True,
     fused: bool = True,
 ) -> jax.Array:
     """Heterogeneous multi-LoRA apply; host buckets requests so each token
@@ -263,15 +218,15 @@ def sgmv_apply(
             group_a=qas[0].group_size,
             bits_b=qbts[0].bits, binary_b=qbts[0].mode == "binary",
             group_b=qbts[0].group_size,
-            tile_t=tile_t, interpret=interpret)
+            tile_t=tile_t)
         return (scaling * y).astype(x.dtype)
     h = sgmv_rhs(x, a_codes, a_scale, a_zero, seg_map,
                  bits=qas[0].bits, binary=qas[0].mode == "binary",
-                 group=qas[0].group_size, tile_t=tile_t, interpret=interpret)
+                 group=qas[0].group_size, tile_t=tile_t)
     y = sgmv_out(h, b_codes, b_scale, b_zero, seg_map,
                  bits=qbts[0].bits, binary=qbts[0].mode == "binary",
                  group=qbts[0].group_size, m=_quant_m(qbts[0]),
-                 tile_t=tile_t, interpret=interpret)
+                 tile_t=tile_t)
     return (scaling * y).astype(x.dtype)
 
 
@@ -287,7 +242,7 @@ def sgmv_apply(
         "seg",
     ),
     meta_fields=("bits_hi", "group_ah", "group_bh", "group_al", "group_bl",
-                 "k", "m", "rank", "tile_t", "interpret", "fold"),
+                 "k", "m", "rank", "tile_t", "fold"),
 )
 @dataclasses.dataclass(frozen=True)
 class PackedLoRABatch:
@@ -345,7 +300,6 @@ class PackedLoRABatch:
     m: int
     rank: int
     tile_t: int
-    interpret: bool
     fold: int = 1
 
 
@@ -355,13 +309,12 @@ def _zero_side(rp: int, dim: int, group: int):
     ``rp`` rows over ``dim`` features (zero scales → dequantizes to 0)."""
     g = min(group, dim)
     ng = -(-dim // g)
-    wpg = -(-g // 8)
-    return (jnp.zeros((rp, ng * wpg), jnp.uint8),
+    return (jnp.zeros((rp, -(-ng * g // 8)), jnp.uint8),
             jnp.zeros((rp, ng), jnp.float32),
             jnp.zeros((rp, ng), jnp.int32))
 
 
-def pack_adapter_layers(qls: Sequence[QuantizedLoRA], interpret: bool = True,
+def pack_adapter_layers(qls: Sequence[QuantizedLoRA],
                         fold: int = 1) -> PackedLoRABatch:
     """Stack one adapter's per-layer :class:`QuantizedLoRA` list into the
     ``(L, Rp, ·)`` kernel layout (an adapter-axis-free
@@ -419,7 +372,7 @@ def pack_adapter_layers(qls: Sequence[QuantizedLoRA], interpret: bool = True,
         bits_hi=bits,
         group_ah=q0.a_high.group_size, group_bh=q0.b_high.group_size,
         group_al=group_al, group_bl=group_bl,
-        k=k, m=m, rank=r, tile_t=1, interpret=interpret, fold=fold,
+        k=k, m=m, rank=r, tile_t=1, fold=fold,
     )
 
 
@@ -561,5 +514,5 @@ def sgmv_apply_packed(x: jax.Array, pb: PackedLoRABatch, *,
         b_lo=(pb.bl_codes, pb.bl_scale, pb.bl_zero),
         bits_lo=1, binary_lo=True,
         group_al=pb.group_al, group_bl=pb.group_bl,
-        m=pb.m, tile_t=pb.tile_t, interpret=pb.interpret)
+        m=pb.m, tile_t=pb.tile_t)
     return (scaling * y).astype(x.dtype)

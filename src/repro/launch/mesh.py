@@ -15,15 +15,22 @@ Axes:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto(n: int):
+    """``jax.make_mesh`` defaults to Explicit axes; the sharding rules here
+    are written for Auto ones (the compiler propagates shardings)."""
+    return (AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh():
     """Whatever this host actually has (CPU smoke runs: 1 device)."""
     n = len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"))
+    return jax.make_mesh((1, n), ("data", "model"), axis_types=_auto(2))

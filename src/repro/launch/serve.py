@@ -19,6 +19,8 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core import LoRAQuantConfig
+from repro.kernels.quant_matmul.kernel import interpret_mode
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving.engine import AdapterStore, MultiLoRAEngine, Request
 from repro.serving.faults import RequestStatus, named_plan
@@ -159,6 +161,7 @@ def main(argv=None):
                    help="continuous mode: print a one-line stats snapshot "
                         "every N scheduler steps (0 = off)")
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, args.preset)
     if args.preset == "smoke":
@@ -245,7 +248,8 @@ def main(argv=None):
         by_status[r.status.value] = by_status.get(r.status.value, 0) + 1
     print(f"[serve] mode={args.mode}: {len(done)} requests "
           f"({', '.join(f'{k}={v}' for k, v in sorted(by_status.items()))}), "
-          f"{total_tokens} tokens in {dt:.2f}s ({total_tokens/dt:.1f} tok/s); "
+          f"{total_tokens} tokens in {dt:.2f}s ({total_tokens/dt:.1f} tok/s"
+          f"{', interpret' if interpret_mode() else ''}); "
           f"fp-resident LoRA bytes: {store.fp_resident_bytes()}")
     bad = [r for r in done if r.status is not RequestStatus.DONE]
     for r in bad[:8]:

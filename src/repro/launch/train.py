@@ -26,6 +26,7 @@ import numpy as np
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig, make_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.step import make_train_step
 from repro.models import build_model
@@ -70,6 +71,7 @@ def main(argv=None):
     p.add_argument("--log-every", type=int, default=10)
     p.add_argument("--fp32", action="store_true", help="CPU smoke precision")
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, args.preset)
     if args.fp32 or args.preset == "smoke":

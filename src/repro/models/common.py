@@ -153,8 +153,6 @@ def linear(
     base: Params,
     lora: Optional[Params] = None,
     scaling: float = 2.0,
-    *,
-    interpret: bool = True,
 ) -> jax.Array:
     """``x @ W (+ LoRA)``. The LoRA path computes in the LoRA dtype and is a
     rank-r bottleneck: (x Aᵀ) Bᵀ — never materializes ΔW.
@@ -185,8 +183,7 @@ def linear(
         from repro.kernels import lora_apply_quantized
 
         x2 = x.reshape(-1, x.shape[-1])
-        upd = lora_apply_quantized(x2, lora, scaling=scaling, fused=True,
-                                   interpret=interpret)
+        upd = lora_apply_quantized(x2, lora, scaling=scaling, fused=True)
         return y + upd.reshape(y.shape).astype(y.dtype)
     if isinstance(lora, PackedLoRABatch):
         from repro.kernels import sgmv_apply_packed

@@ -283,7 +283,7 @@ class AdapterStore:
         self.batched_quantize = batched_quantize
         self.hbm_budget_bytes = hbm_budget_bytes
         self._lru: "collections.OrderedDict[str, Any]" = collections.OrderedDict()
-        self._packed: Dict[Tuple[str, bool], Dict[str, PackedLoRABatch]] = {}
+        self._packed: Dict[str, Dict[str, PackedLoRABatch]] = {}
         self._batch_cache: Dict[tuple, Any] = {}
         self._versions: Dict[str, int] = {}
         self._mutations: int = 0
@@ -293,8 +293,7 @@ class AdapterStore:
 
     def _invalidate(self, adapter_id: str):
         self._lru.pop(adapter_id, None)
-        for flag in (True, False):
-            self._packed.pop((adapter_id, flag), None)
+        self._packed.pop(adapter_id, None)
         self._batch_cache.clear()
 
     def _bump(self, adapter_id: str):
@@ -485,24 +484,20 @@ class AdapterStore:
 
     # ----- packed (serve-from-codes) form -----
 
-    def packed_entries(self, adapter_id: str,
-                       interpret: bool = True) -> Dict[str, PackedLoRABatch]:
+    def packed_entries(self, adapter_id: str) -> Dict[str, PackedLoRABatch]:
         """Per-path packed kernel layouts ``(L, Rp, ·)`` for one adapter,
-        built once from the quantized codes and cached device-resident
-        (keyed by the ``interpret`` flag, which is baked into the leaf)."""
-        key = (adapter_id, interpret)
-        if key not in self._packed:
+        built once from the quantized codes and cached device-resident."""
+        if adapter_id not in self._packed:
             qa = self.quantized[adapter_id]
             folds = _leaf_folds(qa.template)
-            self._packed[key] = {
-                path: pack_adapter_layers(qs, interpret=interpret,
-                                          fold=folds.get(path, 1))
+            self._packed[adapter_id] = {
+                path: pack_adapter_layers(qs, fold=folds.get(path, 1))
                 for path, qs in qa.entries.items()
             }
-        return self._packed[key]
+        return self._packed[adapter_id]
 
     def pack_batch(self, adapter_ids: Sequence[str], like_tree,
-                   tile_t: int = 8, interpret: bool = True) -> Any:
+                   tile_t: int = 8) -> Any:
         """Build a lora tree for a heterogeneous batch over ``adapter_ids``:
         every {'a','b'} leaf becomes a :class:`PackedLoRABatch` stack
         ``(L, NA, Rp, ·)`` in adapter order — or, when the adapters'
@@ -518,12 +513,11 @@ class AdapterStore:
         once); any re-register invalidates the cache. ``like_tree`` only
         provides structure, so the cache key ignores it.
         """
-        key = (tuple(adapter_ids), tile_t, interpret)
+        key = (tuple(adapter_ids), tile_t)
         cached = self._batch_cache.get(key)
         if cached is not None:
             return cached
-        per = [self.packed_entries(a, interpret=interpret)
-               for a in adapter_ids]
+        per = [self.packed_entries(a) for a in adapter_ids]
         sigs = [self.signature_of(a) for a in adapter_ids]
         buckets = sorted(set(sigs))
         na = len(adapter_ids)
@@ -702,7 +696,7 @@ class MultiLoRAEngine:
 
     def __init__(self, model, base_params, store: AdapterStore,
                  cache_capacity: int = 512, mode: str = "continuous",
-                 seg_tile: int = 8, interpret: bool = True,
+                 seg_tile: int = 8,
                  max_rows: int = 8, hbm_slots: Optional[int] = None,
                  queue_limit: Optional[int] = None,
                  queue_policy: str = "reject",
@@ -721,7 +715,6 @@ class MultiLoRAEngine:
         self.capacity = cache_capacity
         self.mode = mode
         self.seg_tile = seg_tile
-        self.interpret = interpret
         self.max_rows = max_rows
         self.hbm_slots = hbm_slots
         self.queue_limit = queue_limit
@@ -929,8 +922,7 @@ class MultiLoRAEngine:
         aidx = np.asarray([ids.index(r.adapter_id) for r in reqs], np.int32)
         tmax = self._tmax(reqs)
         packed = self.store.pack_batch(ids, self.params["lora"],
-                                       tile_t=self.seg_tile,
-                                       interpret=self.interpret)
+                                       tile_t=self.seg_tile)
         # prefill: each padded prompt is tmax rows (a whole number of
         # seg_tile token tiles, all one adapter); decode: one row per
         # sequence, tile_t = 1.
@@ -963,7 +955,7 @@ class MultiLoRAEngine:
 
             self._memory = AdapterMemoryManager(
                 self.store, self.params["lora"], num_slots=self.hbm_slots,
-                tile_t=self.seg_tile, interpret=self.interpret,
+                tile_t=self.seg_tile,
                 transport=self.transport, faults=self.faults,
                 telemetry=self.telemetry)
         return self._memory

@@ -69,7 +69,7 @@ from repro.serving.faults import (
 # PackedLoRABatch cannot silently go un-copied
 _META_FIELDS = tuple(
     f.name for f in dataclasses.fields(PackedLoRABatch)
-    if f.name not in _ARRAY_FIELDS + ("seg", "tile_t", "interpret"))
+    if f.name not in _ARRAY_FIELDS + ("seg", "tile_t"))
 
 
 @jax.jit
@@ -150,7 +150,7 @@ class AdapterMemoryManager:
     """
 
     def __init__(self, store, like_tree, num_slots: Optional[int] = None,
-                 tile_t: int = 8, interpret: bool = True,
+                 tile_t: int = 8,
                  transport: Optional[HostTransport] = None,
                  faults: Optional[FaultPlan] = None,
                  verify_pages: bool = True,
@@ -161,7 +161,6 @@ class AdapterMemoryManager:
         self.like_tree = like_tree
         self.requested_slots = num_slots
         self.tile_t = tile_t
-        self.interpret = interpret
         self.faults = faults
         self.transport = (transport if transport is not None
                           else HostTransport(faults=faults))
@@ -294,8 +293,7 @@ class AdapterMemoryManager:
             meta: Dict[str, Dict[str, Any]] = {}
             nbytes = 0
             for path, n_layers, fold in self._leaves():
-                pb = pack_adapter_layers(qa.entries[path],
-                                         interpret=self.interpret, fold=fold)
+                pb = pack_adapter_layers(qa.entries[path], fold=fold)
                 meta[path] = {f: getattr(pb, f) for f in _META_FIELDS}
                 fields = {}
                 for f in _ARRAY_FIELDS:
@@ -865,8 +863,7 @@ class AdapterMemoryManager:
             fields = dict(pool.arrays[path])
             meta = self._meta_by_sig[pool.sig][path]
             return PackedLoRABatch(**fields, seg=None, **meta,
-                                   tile_t=self.tile_t,
-                                   interpret=self.interpret)
+                                   tile_t=self.tile_t)
 
         def rebuild(node, path):
             if isinstance(node, dict):

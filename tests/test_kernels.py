@@ -1,5 +1,6 @@
-"""Pallas kernel validation (interpret=True) against the pure-jnp oracle:
-shape/dtype sweeps for the fused dequant matmuls and the SGMV variants."""
+"""Pallas kernel validation against the pure-jnp oracle (in the Pallas
+interpreter on a CPU backend): shape/dtype sweeps for the fused dequant
+matmuls and the SGMV variants."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -10,10 +11,16 @@ import dataclasses
 import jax
 
 from repro.core import LoRAQuantConfig, quantize_lora
-from repro.core.quant import binary_quantize, rtn_quantize
+from repro.core.quant import (
+    binary_quantize,
+    rtn_quantize,
+    storage_bits,
+    unpack_codes,
+)
 from repro.kernels.quant_matmul.ops import (
+    FUSED_VMEM_BUDGET,
+    _fused_vmem_estimate,
     _kernel_layout,
-    _pick_tile,
     lora_apply_quantized,
     pack_adapter_layers,
     sgmv_apply,
@@ -22,6 +29,7 @@ from repro.kernels.quant_matmul.ops import (
 )
 from repro.kernels.quant_matmul.kernel import (
     LAUNCH_COUNTS,
+    _unpack_dequant_grouped,
     matmul_out,
     matmul_rhs,
     reset_launch_counts,
@@ -55,8 +63,7 @@ def test_matmul_rhs_vs_ref(t, k, m, mode, bits, xdtype):
     tp = -(-t // 8) * 8
     xp = jnp.pad(x, ((0, tp - t), (0, 0)))
     got = matmul_rhs(xp, codes, scale, zero, bits=q.bits,
-                     binary=(mode == "binary"), tile_t=8,
-                     tile_k=min(k, 256), interpret=True)[:t]
+                     binary=(mode == "binary"), tile_t=8)[:t]
     want = ref_quant_matmul_rhs(x.astype(jnp.float32), q)
     np.testing.assert_allclose(np.asarray(got[:, :r]), np.asarray(want),
                                rtol=2e-2 if xdtype == jnp.bfloat16 else 1e-5,
@@ -75,8 +82,7 @@ def test_matmul_out_vs_ref(t, k, m, mode):
     codes, scale, zero, _ = _kernel_layout(q)
     hp = jnp.pad(h, ((0, -(-t // 8) * 8 - t), (0, codes.shape[0] - r)))
     got = matmul_out(hp, codes, scale, zero, bits=q.bits,
-                     binary=(mode == "binary"), tile_t=8,
-                     tile_m=128, interpret=True)[:t]
+                     binary=(mode == "binary"), tile_t=8)[:t]
     want = ref_quant_matmul_out(h, q)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
@@ -94,7 +100,7 @@ def test_lora_apply_full_pipeline(rho, bits_high):
     ql = quantize_lora(b, a, LoRAQuantConfig(rho=rho, bits_high=bits_high,
                                              ste_steps=0))
     x = _rand((23, n), jnp.float32, seed=9)
-    got = lora_apply_quantized(x, ql, interpret=True)
+    got = lora_apply_quantized(x, ql)
     want = x @ ql.delta_w().T
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
@@ -122,7 +128,7 @@ def test_sgmv_vs_ref(mode, segs):
     seg_ids = np.repeat(segs, tile)
     x = _rand((len(seg_ids), n), jnp.float32, seed=3)
     seg_map = jnp.asarray(np.asarray(segs, np.int32))
-    got = sgmv_apply(x, qas, qbts, seg_map, tile_t=tile, interpret=True)
+    got = sgmv_apply(x, qas, qbts, seg_map, tile_t=tile)
     want = ref_sgmv(x, qas, qbts, seg_ids)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
@@ -161,13 +167,13 @@ def test_fused_lora_apply(bits_high, rho, t):
     ql = _decayed_qlora(m, n, r, rho=rho, bits_high=bits_high, seed=bits_high)
     assert (ql.a_low is None) == (rho == 1.0)
     x = _rand((t, n), jnp.float32, seed=t)
-    got = lora_apply_quantized(x, ql, interpret=True, fused=True)
+    got = lora_apply_quantized(x, ql, fused=True)
     want = x @ ql.delta_w().T
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
     # the legacy two-pass path now covers every width the fused path does
     # (group-aware unpack ported, incl. 3-bit uint32) — sweep parity on both
-    two_pass = lora_apply_quantized(x, ql, interpret=True, fused=False)
+    two_pass = lora_apply_quantized(x, ql, fused=False)
     assert float(jnp.max(jnp.abs(got - two_pass))) <= 1e-3
 
 
@@ -176,7 +182,7 @@ def test_fused_binary_low_path_contributes():
     ql = _decayed_qlora(256, 256, 16, rho=0.3, decay=0.1, seed=5)
     assert ql.a_low is not None
     x = _rand((16, 256), jnp.float32, seed=1)
-    got = lora_apply_quantized(x, ql, interpret=True, fused=True)
+    got = lora_apply_quantized(x, ql, fused=True)
     want = x @ ql.delta_w().T
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
@@ -187,19 +193,19 @@ def test_fused_is_single_pallas_call():
     assert ql.a_low is not None
     x = _rand((16, 384), jnp.float32)
     reset_launch_counts()
-    lora_apply_quantized(x, ql, interpret=True, fused=True)
+    lora_apply_quantized(x, ql, fused=True)
     assert dict(LAUNCH_COUNTS) == {"fused_lora": 1}
     reset_launch_counts()
-    lora_apply_quantized(x, ql, interpret=True, fused=False)
+    lora_apply_quantized(x, ql, fused=False)
     assert dict(LAUNCH_COUNTS) == {"matmul_rhs": 2, "matmul_out": 2}
 
     ql_hi = _decayed_qlora(256, 384, 16, rho=1.0)   # h == r: no low factors
     assert ql_hi.a_low is None
     reset_launch_counts()
-    lora_apply_quantized(x, ql_hi, interpret=True, fused=True)
+    lora_apply_quantized(x, ql_hi, fused=True)
     assert dict(LAUNCH_COUNTS) == {"fused_lora": 1}
     reset_launch_counts()
-    lora_apply_quantized(x, ql_hi, interpret=True, fused=False)
+    lora_apply_quantized(x, ql_hi, fused=False)
     assert dict(LAUNCH_COUNTS) == {"matmul_rhs": 1, "matmul_out": 1}
 
 
@@ -223,11 +229,11 @@ def test_sgmv_fused_vs_two_pass(mode):
     seg_map = jnp.asarray(np.asarray(segs, np.int32))
 
     reset_launch_counts()
-    fused = sgmv_apply(x, qas, qbts, seg_map, tile_t=tile, interpret=True,
+    fused = sgmv_apply(x, qas, qbts, seg_map, tile_t=tile,
                        fused=True)
     assert dict(LAUNCH_COUNTS) == {"sgmv_fused": 1}
     reset_launch_counts()
-    two = sgmv_apply(x, qas, qbts, seg_map, tile_t=tile, interpret=True,
+    two = sgmv_apply(x, qas, qbts, seg_map, tile_t=tile,
                      fused=False)
     assert dict(LAUNCH_COUNTS) == {"sgmv_rhs": 1, "sgmv_out": 1}
     np.testing.assert_allclose(np.asarray(fused), np.asarray(two),
@@ -250,7 +256,7 @@ def _packed_layer(qls, tile_t):
 
 @pytest.mark.parametrize(
     "bits_high",
-    [2, pytest.param(3, marks=pytest.mark.slow)])  # uint32 interpret is slow
+    [2, pytest.param(3, marks=pytest.mark.slow)])  # 3-bit: slow interpreted
 def test_sgmv_packed_mixed_h_vs_ref(bits_high):
     """Mixed-adapter apply straight from packed codes: adapters with
     DIFFERENT split indices h (incl. one with no binary part at all) in one
@@ -330,60 +336,58 @@ def test_sgmv_packed_folded_expert_axis_vs_ref():
 
 
 # --------------------------------------------------------------------------
-# tile-size regression (K > cap whose 2^i·cap chain has no ≥128 divisor)
+# kernel layout: whole bytes per code, exact round trip
 # --------------------------------------------------------------------------
 
-def test_pick_tile_divides():
-    assert _pick_tile(2112, 64) == 704          # old logic picked 128 ∤ 2112
-    assert _pick_tile(2048, 128) == 2048
-    assert _pick_tile(192, 128) == 192          # ≤ cap: single tile
-    assert _pick_tile(4096, 128) == 2048
-    for n, g in [(2112, 64), (2368, 64), (6144, 128), (2176, 128)]:
-        t = _pick_tile(n, g)
-        assert n % t == 0 and t % g == 0 and t <= 2048
+@pytest.mark.parametrize("bits", [1, 2, 3, 4])
+def test_plane_layout_bits_per_code(bits):
+    """The bit-plane-major kernel layout spends exactly ``bits`` per code
+    (K = 3072, 24 groups of 128) and dequantizes to what the stored codes
+    dequantize to."""
+    a = _rand((16, 3072), jnp.float32, seed=bits)
+    q = (binary_quantize(a, 128, axis=1) if bits == 1
+         else rtn_quantize(a, bits, 128, axis=1))
+    codes, scale, zero, _ = _kernel_layout(q)
+    assert codes.dtype == jnp.uint8
+    assert codes.size * 8 == a.size * bits
+    assert storage_bits(q) == a.size * bits + scale.size * (
+        16 + (0 if bits == 1 else bits))
+    got = _unpack_dequant_grouped(codes, scale, None if bits == 1 else zero,
+                                  bits, 128)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(q.dequantize()))
+    assert unpack_codes(q.codes, q.bits, 128).shape == (16, 24, 128)
 
 
 # --------------------------------------------------------------------------
-# large-M VMEM guard: fused auto-falls back to two-pass instead of blowing
-# the per-step VMEM budget at compile time
+# VMEM: the fused kernel keeps whole (tile_t, K) / (tile_t, M) tiles, so a
+# wide output shrinks the token tile instead of leaving the fused path
 # --------------------------------------------------------------------------
 
-def test_fused_vmem_guard_falls_back_to_two_pass():
-    from repro.kernels.quant_matmul.ops import (
-        FUSED_VMEM_BUDGET,
-        _fused_vmem_estimate,
-        _pick_tile,
-    )
-
-    # synthetic large-M shape: the (tile_t, M) output tile alone is
+def test_fused_vmem_shrinks_token_tile():
+    # synthetic large-M shape: the (128, M) output tile alone is
     # 128·32768·4 B = 16 MB > FUSED_VMEM_BUDGET
     m, n, r = 32768, 256, 8
     ql = _decayed_qlora(m, n, r, rho=1.0, seed=13)
-    tk = _pick_tile(n, ql.a_high.group_size)
-    assert _fused_vmem_estimate(ql, 128, tk) > FUSED_VMEM_BUDGET
+    assert _fused_vmem_estimate(ql, 128) > FUSED_VMEM_BUDGET
+    assert _fused_vmem_estimate(ql, 16) <= FUSED_VMEM_BUDGET
     x = _rand((128, n), jnp.float32, seed=14)
     reset_launch_counts()
-    got = lora_apply_quantized(x, ql, interpret=True, fused=True)
-    assert "fused_lora" not in LAUNCH_COUNTS          # guard kicked in
-    assert LAUNCH_COUNTS["matmul_rhs"] == 1 and LAUNCH_COUNTS["matmul_out"] == 1
+    got = lora_apply_quantized(x, ql, fused=True)
+    assert dict(LAUNCH_COUNTS) == {"fused_lora": 1}
     want = x @ ql.delta_w().T
+    assert got.shape == want.shape
     np.testing.assert_allclose(np.asarray(got[:, :512]),
                                np.asarray(want[:, :512]),
                                rtol=1e-4, atol=1e-4)
-    assert got.shape == want.shape
 
 
 def test_fused_vmem_guard_keeps_fused_for_normal_shapes():
     ql = _decayed_qlora(384, 512, 16, rho=0.8, seed=15)
+    assert _fused_vmem_estimate(ql, 128) <= FUSED_VMEM_BUDGET
     x = _rand((16, 512), jnp.float32, seed=16)
     reset_launch_counts()
-    lora_apply_quantized(x, ql, interpret=True, fused=True)
+    lora_apply_quantized(x, ql, fused=True)
     assert dict(LAUNCH_COUNTS) == {"fused_lora": 1}
-    # an explicit tiny budget forces the degrade on the same small shape
-    reset_launch_counts()
-    lora_apply_quantized(x, ql, interpret=True, fused=True, vmem_budget=1)
-    assert "fused_lora" not in LAUNCH_COUNTS
-    assert LAUNCH_COUNTS["matmul_rhs"] == 2 and LAUNCH_COUNTS["matmul_out"] == 2
 
 
 def test_odd_k_apply_regression():
@@ -394,6 +398,6 @@ def test_odd_k_apply_regression():
     x = _rand((9, k), jnp.float32, seed=8)
     want = x @ ql.delta_w().T
     for fused in (True, False):
-        got = lora_apply_quantized(x, ql, interpret=True, fused=fused)
+        got = lora_apply_quantized(x, ql, fused=fused)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-4, atol=1e-4)
