@@ -1,0 +1,161 @@
+"""The command as a check runs it, on the CPU.
+
+With ``BENCH_REHEARSAL=1`` each cell runs end to end at its files'
+``rehearsal`` sizes (Pallas in the interpreter) and prints no metric;
+without it the command refuses a machine with no TPU, and a checkout with
+no program, printing no result."""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH)
+
+
+def _run(args, cwd=ROOT, rehearsal=True, extra_env=None, timeout=900):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("BENCH_REHEARSAL", None)
+    if rehearsal:
+        env["BENCH_REHEARSAL"] = "1"
+    env.update(extra_env or {})
+    return subprocess.run(
+        [sys.executable, os.path.join(cwd, "bench", "run.py"), *args],
+        cwd=cwd, env=env, capture_output=True, text=True, timeout=timeout)
+
+
+def _result(proc):
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def _cells():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in _cells()["workloads"]])
+def test_each_cell_rehearses(cell):
+    spec = _cells()
+    out = _result(_run(["--workload", cell, "--seed", "2147483700",
+                        "--seconds", "4", "--trace", "0"]))
+    assert out["correct"] is True
+    assert out["metrics"] == {}          # nothing from a CPU is reported
+    assert out["attempted"] > 0 and out["failed"] == 0
+    e2e = {m["name"] for m in spec["end_to_end"]
+           if cell in m.get("workloads", [cell])}
+    assert set(out["rehearsal"]["computed"]) == e2e
+    assert list(out)[-1] == "checks"
+    assert out["checks"]["logit_gap"]["value"] <= \
+        out["checks"]["logit_gap"]["limit"]
+
+
+def test_no_tpu_no_result():
+    proc = _run(["--workload", "olmo1b-chat-zipf", "--seed", "1",
+                 "--seconds", "1", "--trace", "0"], rehearsal=False)
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == ""
+    assert "TPU" in proc.stderr
+
+
+def test_without_the_program_no_result(tmp_path):
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    shutil.copytree(BENCH, tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("out", "__pycache__"))
+    proc = _run(["--workload", "olmo1b-chat-zipf", "--seed", "1",
+                 "--seconds", "1", "--trace", "0"], cwd=str(tmp_path))
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == ""
+
+
+def _w64_cell(spec, data):
+    """A test configuration (olmo-1b at a narrower rehearsal width) and a
+    test mix derived from the chat mix."""
+    with open(os.path.join(BENCH, "configs", "olmo-1b.json")) as f:
+        mc = json.load(f)
+    mc.update(name="olmo-1b.w64", rehearsal=dict(mc["rehearsal"], d_model=64,
+                                                  n_heads=2, n_kv_heads=1))
+    with open(os.path.join(BENCH, "traffic", "chat-zipf.json")) as f:
+        mix = json.load(f)
+    mix["rehearsal"].update(prompt_tokens=[8], rate_per_s=3.0)
+    data["configs/olmo-1b.w64.json"] = mc
+    data["traffic/chat-short.json"] = mix
+    spec["configs"].append({"name": "olmo-1b.w64", "source": mc["source"],
+                            "file": "bench/configs/olmo-1b.w64.json",
+                            "reduced": ["d_model"], "why": "test"})
+    spec["workloads"].append({"name": "w64-chat-short",
+                              "config": "olmo-1b.w64",
+                              "traffic": "chat-short", "chips": 1,
+                              "why": "test"})
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        if "workloads" in m:
+            m["workloads"].append("w64-chat-short")
+    return "w64-chat-short", {"itl_p95_ms", "setup_s"}
+
+
+def _backlog_cell(spec, data):
+    """A backlog mix on olmo-1b (a queue that never empties, every adapter
+    resident, drawn uniformly), added as a mix file and a cell entry."""
+    with open(os.path.join(BENCH, "traffic", "chat-zipf.json")) as f:
+        mix = json.load(f)
+    del mix["rate_per_s"]
+    mix.update(arrivals="backlog", backlog=4, block=32)
+    for sizes in (mix, mix["rehearsal"]):
+        sizes.pop("rate_per_s", None)
+        fleet = sizes["fleet"]
+        fleet.update(popularity="uniform")
+        fleet.pop("zipf_alpha")
+        sizes["server"]["hbm_slots"] = fleet["adapters"]
+    data["traffic/decode-batch.json"] = mix
+    with open(os.path.join(BENCH, "configs", "olmo-1b.json")) as f:
+        data["configs/olmo-1b.json"] = json.load(f)
+    spec["workloads"].append({"name": "olmo1b-decode-batch",
+                              "config": "olmo-1b",
+                              "traffic": "decode-batch", "chips": 1,
+                              "why": "test"})
+    return "olmo1b-decode-batch", {"itl_p95_ms", "setup_s"}
+
+
+@pytest.mark.parametrize("add", [_w64_cell, _backlog_cell],
+                         ids=["test-config-and-mix", "backlog-mix"])
+def test_a_cell_added_as_data_only(tmp_path, add):
+    """A configuration, a mix and a cell found by name: data files and
+    ``BENCHMARK.json`` entries, no file of the harness edited."""
+    spec, data = _cells(), {}
+    cell, e2e = add(spec, data)
+    data[f"limits/{cell}.json"] = {"logit_gap": 1.0,
+                                   "rehearsal": {"logit_gap": 1e-3}}
+    for name, content in data.items():
+        path = tmp_path / "bench" / name
+        path.parent.mkdir(parents=True, exist_ok=True)
+        path.write_text(json.dumps(content))
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    out = _result(_run(["--workload", cell, "--seed", "5",
+                        "--seconds", "3", "--trace", "0"],
+                       extra_env={"BENCH_SPEC": str(tmp_path /
+                                                    "BENCHMARK.json")}))
+    assert out["correct"] is True
+    assert out["attempted"] > 0 and out["failed"] == 0
+    assert set(out["rehearsal"]["computed"]) == e2e
+
+
+def test_calibrate_reads_program_control_and_faults():
+    """``calibrate.py`` at rehearsal sizes: the program's reading passes the
+    harness's decision; the control's and every planted fault's fail it."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_REHEARSAL="1")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "calibrate.py"), "--workload",
+         "olmo1b-chat-zipf", "--seconds", "3", "--seeds", "4",
+         "--fault-seeds", "4"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=900)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    lines = [json.loads(x) for x in proc.stdout.strip().splitlines()]
+    correct = {x["run"]: x["correct"] for x in lines}
+    assert correct.pop("program") is True
+    assert correct == {"control": False, "cache_unchanged": False,
+                       "half_batch": False, "page_not_written": False,
+                       "token_altered": False}
