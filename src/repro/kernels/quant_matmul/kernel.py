@@ -55,37 +55,17 @@ from jax.experimental.pallas import tpu as pltpu
 # Trace-time kernel-launch accounting. Every kernel builder below records its
 # name here once per ``pallas_call`` issued (the apply wrappers in ops.py are
 # deliberately unjitted, so one logical apply == one recorded trace). Used by
-# tests and benchmarks to assert fused-vs-two-pass launch counts, and by
-# the serving telemetry layer (via launch *sinks*) to export the same
-# counts as first-class ``pallas_launches_total{kernel=...}`` metrics.
+# tests and benchmarks to assert fused-vs-two-pass launch counts. It counts
+# traces, not runs: a compiled program replayed on a warm server adds none.
 LAUNCH_COUNTS: "collections.Counter[str]" = collections.Counter()
-
-# Registered observers: each is called with the kernel name at every
-# recorded launch. Sinks must be cheap and must not raise — they run at
-# jit trace time inside kernel builders.
-_LAUNCH_SINKS: list = []
 
 
 def reset_launch_counts() -> None:
     LAUNCH_COUNTS.clear()
 
 
-def add_launch_sink(sink) -> None:
-    """Register a ``sink(name)`` callable observing every kernel launch
-    (idempotent: re-adding an already-registered sink is a no-op)."""
-    if sink not in _LAUNCH_SINKS:
-        _LAUNCH_SINKS.append(sink)
-
-
-def remove_launch_sink(sink) -> None:
-    if sink in _LAUNCH_SINKS:
-        _LAUNCH_SINKS.remove(sink)
-
-
 def _record_launch(name: str) -> None:
     LAUNCH_COUNTS[name] += 1
-    for sink in _LAUNCH_SINKS:
-        sink(name)
 
 
 def interpret_mode() -> bool:

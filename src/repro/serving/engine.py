@@ -79,7 +79,7 @@ from repro.serving.faults import (
     UnknownAdapter,
     validate_lora_tree,
 )
-from repro.serving.telemetry import Telemetry
+from repro.serving.telemetry import Telemetry, span
 
 
 def iter_lora_linears(lora_tree) -> List[Tuple[str, Any]]:
@@ -735,10 +735,9 @@ class MultiLoRAEngine:
             self.clock = telemetry.clock
         else:
             self.clock = time.perf_counter
-        if telemetry is not None:
-            telemetry.install_kernel_counter()
         self._wave = 0                    # admission-wave ordinal (telemetry)
-        self._step_count = 0
+        self._step_count = 0              # decode steps
+        self._step_calls = 0              # step() calls that did work
         self.pending: List[Request] = []
         # adapters quarantined at fault time: id -> store version when
         # quarantined (a re-register bumps the version and auto-clears)
@@ -1015,33 +1014,39 @@ class MultiLoRAEngine:
         request to its adapter's (already pinned) HBM slot — the SGMV
         segment id; a request whose page was faulted in this step is simply
         queued behind the swap-in by dispatch order."""
+        tel = self.telemetry
         tpad = self._tpad(reqs[0])
-        sidx = np.asarray(slots, np.int32)
-        starts = np.asarray([tpad - len(r.prompt) for r in reqs], np.int32)
-        toks = np.stack([
-            np.pad(np.asarray(r.prompt), (tpad - len(r.prompt), 0))
-            for r in reqs
-        ]).astype(np.int32)
-        self._wave += 1
-        if self.telemetry is not None:
-            for req, row_idx in zip(reqs, rows):
-                self.telemetry.on_admit(req.request_id, self._wave, row_idx)
-        t_pre = self.clock()
-        # fetch the tree AFTER acquire()s: this step's swap-ins are in it
-        packed = self.memory.serving_tree()
-        pre = {"base": self.params["base"],
-               "lora": {"groups": packed["groups"],
-                        "seg": jnp.asarray(np.repeat(sidx, tpad))}}
-        logits, grp_caches = self._prefill(
-            pre, {"tokens": jnp.asarray(toks), "start": jnp.asarray(starts)})
-        firsts = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
+        with span("engine.prefill", tel, rows=len(reqs), tpad=tpad):
+            sidx = np.asarray(slots, np.int32)
+            starts = np.asarray([tpad - len(r.prompt) for r in reqs],
+                                np.int32)
+            toks = np.stack([
+                np.pad(np.asarray(r.prompt), (tpad - len(r.prompt), 0))
+                for r in reqs
+            ]).astype(np.int32)
+            self._wave += 1
+            if tel is not None:
+                for req, row_idx in zip(reqs, rows):
+                    tel.on_admit(req.request_id, self._wave, row_idx)
+            t_pre = self.clock()
+            # fetch the tree AFTER acquire()s: this step's swap-ins are in it
+            packed = self.memory.serving_tree()
+            pre = {"base": self.params["base"],
+                   "lora": {"groups": packed["groups"],
+                            "seg": jnp.asarray(np.repeat(sidx, tpad))}}
+            logits, grp_caches = self._prefill(
+                pre, {"tokens": jnp.asarray(toks),
+                      "start": jnp.asarray(starts)})
+        with span("engine.prefill.sync", tel):
+            firsts = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
         now = self.clock()
-        if self.telemetry is not None:
-            self.telemetry.on_prefill(self._wave,
-                                      [r.request_id for r in reqs], int(tpad),
-                                      now - t_pre)
-        self._caches = self._scatter_rows(
-            self._caches, grp_caches, jnp.asarray(np.asarray(rows, np.int32)))
+        if tel is not None:
+            tel.on_prefill(self._wave, [r.request_id for r in reqs],
+                           int(tpad), now - t_pre)
+        with span("engine.scatter", tel, rows=len(rows)):
+            self._caches = self._scatter_rows(
+                self._caches, grp_caches,
+                jnp.asarray(np.asarray(rows, np.int32)))
         out = []
         for b, (req, row_idx) in enumerate(zip(reqs, rows)):
             req.t_first = now
@@ -1187,77 +1192,103 @@ class MultiLoRAEngine:
            ``output`` set, status DONE) is returned.
 
         Returns the requests that reached a terminal state during this
-        step, completion-ordered.
+        step, completion-ordered. The step and each of its phases run under
+        a named :class:`~repro.serving.telemetry.span` (``engine.step``,
+        ``engine.sweep``, ``engine.admit``, ``engine.decode``, ...; the
+        catalogue is in ``docs/observability.md``).
         """
         finished: List[Request] = list(self._terminated)
         self._terminated = []
         if not self.pending and all(r is None for r in self._rows):
             return finished
+        t_step = self.clock()
+        self._step_calls += 1
+        with span("engine.step", self.telemetry,
+                  step=self._step_calls) as root:
+            rows, admitted = self._step(finished, t_step)
+            root.set(rows=rows, admitted=admitted, pending=len(self.pending))
+        return finished
+
+    def _step(self, finished: List[Request],
+              t_step: float) -> Tuple[int, int]:
+        """The body of :meth:`step`, under its ``engine.step`` span: appends
+        the step's terminal requests to ``finished`` and returns the rows
+        it decoded and the requests it admitted."""
+        tel = self.telemetry
         mgr = self.memory
-        mgr.refresh()                      # reconcile store mutations
-        t_step = now = self.clock()
-        # queue-deadline sweep: expired waiters retire without a row
-        still: List[Request] = []
-        for r in self.pending:
-            err = self._queue_expired(r, now)
-            if err is not None:
-                finished.append(
-                    self._finalize(r, RequestStatus.TIMED_OUT, err))
-            else:
-                still.append(r)
-        self.pending = still
-        # poison sweep: the memory layer records integrity failures it
-        # detects at page-read time; DRAIN them into quarantine, skipping
-        # records whose adapter was re-registered since the failure (a
-        # fixed upload must not be re-quarantined), and evict their rows
-        # FAILED, leaving co-batched rows token-exact
-        while mgr.poisoned:
-            aid, ver = mgr.poisoned.popitem()
-            if self.store.version(aid) == ver:
-                self.quarantined[aid] = ver
-        for i in range(self.max_rows):
-            row = self._rows[i]
-            if row is None:
-                continue
-            if self._is_quarantined(row.req.adapter_id):
-                finished.append(self._retire(
-                    i, RequestStatus.FAILED, PoisonedAdapter(
-                        f"request {row.req.request_id}: adapter "
-                        f"{row.req.adapter_id!r} was quarantined "
-                        f"mid-decode", adapter_id=row.req.adapter_id)))
-                continue
-            req = row.req
-            if (req.deadline_ms is not None and req.t_submit is not None
-                    and (now - req.t_submit) * 1e3 > req.deadline_ms):
-                finished.append(self._retire(
-                    i, RequestStatus.TIMED_OUT, DeadlineExceeded(
-                        f"request {req.request_id}: total deadline "
-                        f"({req.deadline_ms:g} ms) expired mid-decode",
-                        adapter_id=req.adapter_id)))
+        with span("engine.sweep", tel) as sweep:
+            n_done = len(finished)
+            mgr.refresh()                  # reconcile store mutations
+            now = self.clock()
+            # queue-deadline sweep: expired waiters retire without a row
+            still: List[Request] = []
+            for r in self.pending:
+                err = self._queue_expired(r, now)
+                if err is not None:
+                    finished.append(
+                        self._finalize(r, RequestStatus.TIMED_OUT, err))
+                else:
+                    still.append(r)
+            self.pending = still
+            # poison sweep: the memory layer records integrity failures it
+            # detects at page-read time; DRAIN them into quarantine,
+            # skipping records whose adapter was re-registered since the
+            # failure (a fixed upload must not be re-quarantined), and
+            # evict their rows FAILED, leaving co-batched rows token-exact
+            while mgr.poisoned:
+                aid, ver = mgr.poisoned.popitem()
+                if self.store.version(aid) == ver:
+                    self.quarantined[aid] = ver
+            for i in range(self.max_rows):
+                row = self._rows[i]
+                if row is None:
+                    continue
+                if self._is_quarantined(row.req.adapter_id):
+                    finished.append(self._retire(
+                        i, RequestStatus.FAILED, PoisonedAdapter(
+                            f"request {row.req.request_id}: adapter "
+                            f"{row.req.adapter_id!r} was quarantined "
+                            f"mid-decode", adapter_id=row.req.adapter_id)))
+                    continue
+                req = row.req
+                if (req.deadline_ms is not None and req.t_submit is not None
+                        and (now - req.t_submit) * 1e3 > req.deadline_ms):
+                    finished.append(self._retire(
+                        i, RequestStatus.TIMED_OUT, DeadlineExceeded(
+                            f"request {req.request_id}: total deadline "
+                            f"({req.deadline_ms:g} ms) expired mid-decode",
+                            adapter_id=req.adapter_id)))
+            sweep.set(expired=len(finished) - n_done)
         if self._caches is None:
             self._caches = self.model.init_cache(self.max_rows, self.capacity)
         # admit FIFO, batching the leading run of equal padded lengths into
         # one prefill; retiring-at-admission frees rows for the next group
-        admitted_any = False
+        admitted = 0
         while self.pending:
             free = [i for i in range(self.max_rows) if self._rows[i] is None]
             if not free:
                 break
-            group = self._select_admissions(len(free), finished)
-            if not group:
-                break
-            admitted_any = True
-            # global slot ids are read AFTER the whole group's acquires: a
-            # later acquire may grow a pool and shift earlier ids
-            slots = [mgr.slot_of(r.adapter_id) for r in group]
-            rows = free[:len(group)]
-            for row_idx, row in zip(rows,
-                                    self._admit_group(group, rows, slots)):
-                if self._row_done(row):
-                    finished.append(self._retire(row_idx))
+            with span("engine.admit", tel) as admit:
+                with span("engine.select", tel) as select:
+                    group = self._select_admissions(len(free), finished)
+                    select.set(picked=len(group))
+                admit.set(rows=len(group),
+                          tpad=self._tpad(group[0]) if group else 0)
+                if not group:
+                    break
+                admitted += len(group)
+                # global slot ids are read AFTER the whole group's
+                # acquires: a later acquire may grow a pool and shift
+                # earlier ids
+                slots = [mgr.slot_of(r.adapter_id) for r in group]
+                rows = free[:len(group)]
+                for row_idx, row in zip(
+                        rows, self._admit_group(group, rows, slots)):
+                    if self._row_done(row):
+                        finished.append(self._retire(row_idx))
         active = [i for i in range(self.max_rows) if self._rows[i] is not None]
         if not active:
-            if self.pending and not admitted_any and not finished:
+            if self.pending and not admitted and not finished:
                 # nothing live to ever unpin a slot (externally pinned
                 # pool): bounded patience, then shed the head so run()
                 # can never spin forever
@@ -1274,54 +1305,64 @@ class MultiLoRAEngine:
             else:
                 self._stalled_steps = 0
             self._prefetch_upcoming()
-            return finished
+            return 0, admitted
         self._stalled_steps = 0
-        toks = np.zeros((self.max_rows, 1), np.int32)
-        pos = np.zeros((self.max_rows,), np.int32)
-        # inactive rows: valid_start == capacity masks every cache slot, so
-        # they decode garbage finitely (NEG_INF masking) and touch nothing.
-        start = np.full((self.max_rows,), self.capacity, np.int32)
-        seg = np.zeros((self.max_rows,), np.int32)
-        for i in active:
-            row = self._rows[i]
-            toks[i, 0] = row.emitted[-1]
-            pos[i] = row.start + row.prompt_len + len(row.emitted) - 1
-            start[i] = row.start
-            # seg ids ARE (global) slot ids: the page is pinned at
-            # admission, but its global id can shift when an earlier
-            # recipe pool grows — read the current id every step (must
-            # happen BEFORE the prefetch below, which may grow pools)
-            seg[i] = mgr.slot_of(row.req.adapter_id)
-        packed = mgr.serving_tree()
-        # the tile_t=1 decode view of the slot pool is rebuilt only when the
-        # pool changed (serving_tree caches until a swap-in/growth dirties
-        # it, so object identity is the change signal; keeping the strong
-        # reference in _dec_src is what makes identity a safe key)
-        if self._dec_src is not packed:
-            self._dec_groups = retile_packed(packed, 1)["groups"]
-            self._dec_src = packed
-        dec = {"base": self.params["base"],
-               "lora": {"groups": self._dec_groups,
-                        "seg": jnp.asarray(seg)}}
+        with span("engine.decode.prep", tel) as prep:
+            toks = np.zeros((self.max_rows, 1), np.int32)
+            pos = np.zeros((self.max_rows,), np.int32)
+            # inactive rows: valid_start == capacity masks every cache
+            # slot, so they decode garbage finitely (NEG_INF masking) and
+            # touch nothing.
+            start = np.full((self.max_rows,), self.capacity, np.int32)
+            seg = np.zeros((self.max_rows,), np.int32)
+            for i in active:
+                row = self._rows[i]
+                toks[i, 0] = row.emitted[-1]
+                pos[i] = row.start + row.prompt_len + len(row.emitted) - 1
+                start[i] = row.start
+                # seg ids ARE (global) slot ids: the page is pinned at
+                # admission, but its global id can shift when an earlier
+                # recipe pool grows — read the current id every step (must
+                # happen BEFORE the prefetch below, which may grow pools)
+                seg[i] = mgr.slot_of(row.req.adapter_id)
+            packed = mgr.serving_tree()
+            # the tile_t=1 decode view of the slot pool is rebuilt only when
+            # the pool changed (serving_tree caches until a swap-in/growth
+            # dirties it, so object identity is the change signal; keeping
+            # the strong reference in _dec_src is what makes identity a
+            # safe key)
+            retiled = self._dec_src is not packed
+            if retiled:
+                self._dec_groups = retile_packed(packed, 1)["groups"]
+                self._dec_src = packed
+            dec = {"base": self.params["base"],
+                   "lora": {"groups": self._dec_groups,
+                            "seg": jnp.asarray(seg)}}
+            prep.set(retiled=int(retiled))
         # stage next wave AFTER building this step's view, BEFORE dispatch:
         # the swap-in copies and the decode below have no data dependency
         self._prefetch_upcoming()
-        logits, self._caches = self._decode(
-            dec, jnp.asarray(toks), self._caches,
-            jnp.asarray(pos), jnp.asarray(start))
-        nxt = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
+        with span("engine.decode", tel):
+            logits, self._caches = self._decode(
+                dec, jnp.asarray(toks), self._caches,
+                jnp.asarray(pos), jnp.asarray(start))
+        with span("engine.decode.sync", tel):
+            nxt = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
         self._step_count += 1
-        if self.telemetry is not None:
-            self.telemetry.on_decode_step(
+        if tel is not None:
+            tel.on_decode_step(
                 self._step_count, self.clock() - t_step, len(active),
                 self.max_rows, len(self.pending),
                 request_ids=[self._rows[i].req.request_id for i in active])
-        for i in active:
-            row = self._rows[i]
-            row.emitted.append(int(nxt[i]))
-            if self._row_done(row):
-                finished.append(self._retire(i))
-        return finished
+        with span("engine.retire", tel) as retire:
+            n_done = len(finished)
+            for i in active:
+                row = self._rows[i]
+                row.emitted.append(int(nxt[i]))
+                if self._row_done(row):
+                    finished.append(self._retire(i))
+            retire.set(retired=len(finished) - n_done)
+        return len(active), admitted
 
     @property
     def active_rows(self) -> int:

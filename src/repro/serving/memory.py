@@ -63,6 +63,7 @@ from repro.serving.faults import (
     PoisonedAdapter,
     page_arrays_finite,
 )
+from repro.serving.telemetry import span
 
 # page meta = everything that isn't a packed array, the late-attached seg,
 # or a per-view knob — derived from the dataclass so a new field added to
@@ -654,11 +655,13 @@ class AdapterMemoryManager:
         ``slot`` as ONE jitted dispatch over every leaf array. Functional
         update: the previous pool buffers stay valid for any
         already-dispatched step, the next-built tree reads the new ones."""
-        page = self._host_page(adapter_id)
-        pool = self._pools[sig]
-        starts = {path: {f: jnp.int32(slot * fold) for f in _ARRAY_FIELDS}
-                  for path, _, fold in self._leaves()}
-        pool.arrays = _page_write(pool.arrays, page.arrays, starts)
+        with span("memory.swap_in", self.telemetry) as swap:
+            page = self._host_page(adapter_id)
+            swap.set(bytes=page.nbytes)
+            pool = self._pools[sig]
+            starts = {path: {f: jnp.int32(slot * fold) for f in _ARRAY_FIELDS}
+                      for path, _, fold in self._leaves()}
+            pool.arrays = _page_write(pool.arrays, page.arrays, starts)
         pool.owners[slot] = adapter_id
         self._where[adapter_id] = (sig, slot)
         self._slot_version[adapter_id] = page.version
@@ -697,39 +700,42 @@ class AdapterMemoryManager:
         grows; the engine re-reads :meth:`slot_of` when building each
         step's seg ids.
         """
-        sig = self._sig_of(adapter_id)
-        if self.resident(adapter_id):
-            self.hits += 1
-            self._count(sig, "hits")
-            local = self._where[adapter_id][1]
-        else:
-            loc = self._where.get(adapter_id)
-            stale_local = (loc[1] if loc is not None and loc[0] == sig
-                           else None)
-            if stale_local is not None:
-                local = stale_local            # resident but stale codes:
-            else:                              # reload in place
-                if loc is not None:            # recipe changed pools
-                    self._free_slot(adapter_id)
-                local = self._find_slot(sig)
-                if local is None:
-                    return None                # retried next step — not
-            self.misses += 1                   # charged as a miss
-            self._count(sig, "misses")
-            try:
-                self._swap_in(adapter_id, sig, local)
-            except HostReadError:
-                if stale_local is None:
-                    raise
-                # degradation rung 1: the slot still holds the last good
-                # version of this adapter's codes — serve those
-                self._count_stale()
-        self._lru[adapter_id] = None
-        self._lru.move_to_end(adapter_id)
-        self._reserved.discard(adapter_id)
-        if pin:
-            self.pin(adapter_id)
-        return self._base(sig) + local
+        with span("memory.acquire", self.telemetry) as acq:
+            sig = self._sig_of(adapter_id)
+            if self.resident(adapter_id):
+                acq.set(hit=1)
+                self.hits += 1
+                self._count(sig, "hits")
+                local = self._where[adapter_id][1]
+            else:
+                acq.set(hit=0)
+                loc = self._where.get(adapter_id)
+                stale_local = (loc[1] if loc is not None and loc[0] == sig
+                               else None)
+                if stale_local is not None:
+                    local = stale_local        # resident but stale codes:
+                else:                          # reload in place
+                    if loc is not None:        # recipe changed pools
+                        self._free_slot(adapter_id)
+                    local = self._find_slot(sig)
+                    if local is None:
+                        return None            # retried next step — not
+                self.misses += 1               # charged as a miss
+                self._count(sig, "misses")
+                try:
+                    self._swap_in(adapter_id, sig, local)
+                except HostReadError:
+                    if stale_local is None:
+                        raise
+                    # degradation rung 1: the slot still holds the last
+                    # good version of this adapter's codes — serve those
+                    self._count_stale()
+            self._lru[adapter_id] = None
+            self._lru.move_to_end(adapter_id)
+            self._reserved.discard(adapter_id)
+            if pin:
+                self.pin(adapter_id)
+            return self._base(sig) + local
 
     def prefetch(self, adapter_ids: Sequence[str]):
         """Stage the next admission wave's pages one step ahead.
@@ -741,35 +747,39 @@ class AdapterMemoryManager:
         prefetch call re-derives the reservation set. Misses here are not
         charged to the hit-rate (only admission-time :meth:`acquire` is).
         """
-        reserved: Set[str] = set()
-        for aid in adapter_ids:
-            if self.store.version(aid) is None:
-                continue
-            sig = self._sig_of(aid)
-            if not self.resident(aid):
-                loc = self._where.get(aid)
-                if loc is not None and loc[0] == sig:
-                    slot = loc[1]
-                else:
-                    if loc is not None:
-                        self._free_slot(aid)
-                    self._reserved = reserved      # protect earlier stages
-                    slot = self._find_slot(sig)
-                    if slot is None:
-                        self._count_prefetch("no_slot")
-                        continue
-                try:
-                    self._swap_in(aid, sig, slot)
-                except (HostReadError, PoisonedAdapter):
-                    self._count_prefetch("failed")
-                    continue       # prefetch is opportunistic: admission's
-                self._count_prefetch("staged")
-            else:                  # acquire surfaces the error properly
-                self._count_prefetch("hit")
-            self._lru[aid] = None
-            self._lru.move_to_end(aid)
-            reserved.add(aid)
-        self._reserved = reserved
+        with span("memory.prefetch", self.telemetry) as pre:
+            reserved: Set[str] = set()
+            staged = 0
+            for aid in adapter_ids:
+                if self.store.version(aid) is None:
+                    continue
+                sig = self._sig_of(aid)
+                if not self.resident(aid):
+                    loc = self._where.get(aid)
+                    if loc is not None and loc[0] == sig:
+                        slot = loc[1]
+                    else:
+                        if loc is not None:
+                            self._free_slot(aid)
+                        self._reserved = reserved  # protect earlier stages
+                        slot = self._find_slot(sig)
+                        if slot is None:
+                            self._count_prefetch("no_slot")
+                            continue
+                    try:
+                        self._swap_in(aid, sig, slot)
+                    except (HostReadError, PoisonedAdapter):
+                        self._count_prefetch("failed")
+                        continue   # prefetch is opportunistic: admission's
+                    self._count_prefetch("staged")
+                    staged += 1
+                else:              # acquire surfaces the error properly
+                    self._count_prefetch("hit")
+                self._lru[aid] = None
+                self._lru.move_to_end(aid)
+                reserved.add(aid)
+            self._reserved = reserved
+            pre.set(staged=staged)
 
     def refresh(self):
         """Reconcile with store mutations (register / re-register with new

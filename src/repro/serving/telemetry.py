@@ -13,20 +13,29 @@ instrumented against ONE dependency-free layer (``docs/observability.md``):
   JSON object per lifecycle event, stable schema — see ``EVENT_SCHEMA``)
   and a **Chrome-trace JSON** (``chrome://tracing`` / Perfetto) of spans.
 * :class:`Telemetry` — the facade the serving layers talk to: it owns the
-  registry, the trace table, the event log, and the **injectable
-  monotonic clock** (:class:`ManualClock` under test, ``time.perf_counter``
-  in production) that makes every timestamp deterministic in CI.
+  registry, the trace table, the event log, the recorded spans, and the
+  **injectable monotonic clock** (:class:`ManualClock` under test,
+  ``time.perf_counter`` in production) that makes every timestamp
+  deterministic in CI.
+* :class:`span` — the one span primitive of the serving step. Every span
+  opens a ``jax.profiler.TraceAnnotation`` (a ``StepTraceAnnotation`` for
+  a step root), so it lands on the profiler's host plane on the device
+  trace's clock; with a :class:`Telemetry` attached it is also recorded in
+  memory on the telemetry clock, nested, and exported by
+  :meth:`Telemetry.chrome_trace`.
 
-Nothing here imports jax, numpy, or any serving module — RPC layers and
-benchmarks can reuse the registry standalone. The serving layers accept
-``telemetry=None`` and skip every hook when unset; instrumentation is
-host-side bookkeeping only and never changes tokens or kernel launches
-(asserted in ``tests/test_telemetry.py``).
+Nothing here imports numpy or any serving module, and jax only lazily, for
+the profiler annotation — RPC layers and benchmarks can reuse the registry
+standalone, without jax. The serving layers accept ``telemetry=None`` and
+skip every in-memory hook when unset; instrumentation is host-side
+bookkeeping only and never changes tokens or kernel launches (asserted in
+``tests/test_telemetry.py``).
 """
 
 from __future__ import annotations
 
 import bisect
+import functools
 import json
 import math
 import time
@@ -34,7 +43,8 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "ManualClock", "MetricsRegistry",
-    "RequestTrace", "Telemetry", "DEFAULT_LATENCY_BUCKETS", "EVENT_SCHEMA",
+    "RequestTrace", "SpanRecord", "Telemetry", "DEFAULT_LATENCY_BUCKETS",
+    "EVENT_SCHEMA", "span",
 ]
 
 
@@ -351,13 +361,107 @@ class RequestTrace:
         return self.end_ts - self.submit_ts
 
 
+class _NoAnnotation:
+    """Stand-in for ``jax.profiler.TraceAnnotation`` where jax is absent."""
+
+    def __init__(self, name: str, **kwargs):
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def set_metadata(self, **kwargs) -> None:
+        pass
+
+
+@functools.lru_cache(maxsize=None)
+def _annotations() -> tuple:
+    """``(TraceAnnotation, StepTraceAnnotation)``: jax's profiler
+    annotations, imported on first use so this module loads without jax."""
+    try:
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+    except ImportError:
+        return _NoAnnotation, _NoAnnotation
+    return TraceAnnotation, StepTraceAnnotation
+
+
+class SpanRecord:
+    """One span recorded in memory on the telemetry clock. ``parent`` is
+    the index in :attr:`Telemetry.spans` of the span that was open around
+    it (None at the root); ``step`` the step number of the step root it
+    belongs to; ``end`` is None while the span is open."""
+
+    __slots__ = ("name", "start", "end", "parent", "step", "counts")
+
+    def __init__(self, name: str, start: float, parent: Optional[int],
+                 step: Optional[int], counts: Dict[str, Any]):
+        self.name = name
+        self.start = start
+        self.end: Optional[float] = None
+        self.parent = parent
+        self.step = step
+        self.counts = counts
+
+
+class span:
+    """A named region of the serving path, with the counts taken at its
+    boundary::
+
+        with span("engine.decode.prep", telemetry) as s:
+            ...
+            s.set(retiled=1)
+
+    Always opens a ``jax.profiler.TraceAnnotation(name, **counts)`` — a
+    ``StepTraceAnnotation(name, step_num=step)`` where ``step`` is given,
+    which makes the span a step root — so the span lands on the profiler's
+    host plane, on the device trace's clock. With no profiler running that
+    costs about a microsecond and the counts are not encoded. With a
+    :class:`Telemetry` the span is also recorded in ``telemetry.spans``
+    with its parent, its step (inherited from the enclosing step root) and
+    its counts; ``telemetry=None`` records nothing in memory. :meth:`set`
+    adds counts known only at the end."""
+
+    __slots__ = ("_ann", "_tel", "_name", "_step", "_counts", "_rec")
+
+    def __init__(self, name: str, telemetry: Optional["Telemetry"] = None,
+                 *, step: Optional[int] = None, **counts):
+        trace, step_trace = _annotations()
+        self._ann = (trace(name, **counts) if step is None
+                     else step_trace(name, step_num=step, **counts))
+        self._tel = telemetry
+        self._name = name
+        self._step = step
+        self._counts = counts
+        self._rec: Optional[SpanRecord] = None
+
+    def __enter__(self) -> "span":
+        self._ann.__enter__()
+        if self._tel is not None:
+            self._rec = self._tel._open_span(self._name, self._step,
+                                             self._counts)
+        return self
+
+    def set(self, **counts) -> None:
+        self._ann.set_metadata(**counts)
+        if self._rec is not None:
+            self._rec.counts.update(counts)
+
+    def __exit__(self, *exc) -> None:
+        if self._rec is not None:
+            self._tel._close_span(self._rec)
+        self._ann.__exit__(*exc)
+
+
 class Telemetry:
     """The facade the serving layers record into.
 
-    One instance spans the whole serving stack: the engine, the paged
-    adapter memory, and (via :meth:`install_kernel_counter`) the Pallas
-    launch recorder all write to ``self.registry``; per-request lifecycle
-    lands in ``self.traces`` and the append-only ``self.events`` log.
+    One instance spans the whole serving stack: the engine and the paged
+    adapter memory write to ``self.registry`` and record their
+    :class:`span`\\ s in ``self.spans``; per-request lifecycle lands in
+    ``self.traces`` and the append-only ``self.events`` log.
 
     Exports:
 
@@ -365,7 +469,7 @@ class Telemetry:
     * :meth:`to_jsonl` / :meth:`write_jsonl` — the event log,
     * :meth:`chrome_trace` / :meth:`write_chrome_trace` — a
       ``chrome://tracing`` / Perfetto span profile (request rows show
-      queue/decode spans, the scheduler row shows prefill/step spans).
+      queue/decode spans, the scheduler row shows the recorded spans).
     """
 
     def __init__(self, clock: Optional[Callable[[], float]] = None):
@@ -373,10 +477,27 @@ class Telemetry:
         self.registry = MetricsRegistry()
         self.traces: Dict[int, RequestTrace] = {}
         self.events: List[Dict[str, Any]] = []
-        self._kernel_sink: Optional[Callable[[str], None]] = None
+        self.spans: List[SpanRecord] = []
+        self._open: List[int] = []    # indices of open spans, innermost last
 
     def now(self) -> float:
         return self.clock()
+
+    # ----- spans (opened and closed by :class:`span`) -----
+
+    def _open_span(self, name: str, step: Optional[int],
+                   counts: Dict[str, Any]) -> SpanRecord:
+        parent = self._open[-1] if self._open else None
+        if step is None and parent is not None:
+            step = self.spans[parent].step
+        rec = SpanRecord(name, self.now(), parent, step, dict(counts))
+        self._open.append(len(self.spans))
+        self.spans.append(rec)
+        return rec
+
+    def _close_span(self, rec: SpanRecord) -> None:
+        rec.end = self.now()
+        self._open.pop()
 
     # ----- event log -----
 
@@ -427,7 +548,8 @@ class Telemetry:
             help="requests per admission wave").observe(len(request_ids))
         self.registry.histogram(
             "serving_prefill_seconds",
-            help="admission prefill dispatch latency").observe(dur_s)
+            help="admission prefill latency: dispatch, then the wait for "
+                 "the first tokens' read-back").observe(dur_s)
 
     def on_first_token(self, request_id: int) -> None:
         tr = self.traces.get(request_id)
@@ -484,35 +606,6 @@ class Telemetry:
                 "serving_ttft_seconds", help="submit -> first token",
                 status=status).observe(tr.ttft_s)
 
-    # ----- kernel launch accounting -----
-
-    def install_kernel_counter(self) -> None:
-        """Promote the kernels' trace-time launch recorder into a
-        first-class counter: every ``pallas_call`` issued while installed
-        increments ``pallas_launches_total{kernel=...}`` (launches happen
-        at jit trace time — steady-state steps replay the compiled
-        program, so a hot serving loop adds none)."""
-        if self._kernel_sink is not None:
-            return
-        from repro.kernels.quant_matmul.kernel import add_launch_sink
-
-        def sink(name: str) -> None:
-            self.registry.counter(
-                "pallas_launches_total",
-                help="pallas_call launches recorded at trace time",
-                kernel=name).inc()
-
-        self._kernel_sink = sink
-        add_launch_sink(sink)
-
-    def uninstall_kernel_counter(self) -> None:
-        if self._kernel_sink is None:
-            return
-        from repro.kernels.quant_matmul.kernel import remove_launch_sink
-
-        remove_launch_sink(self._kernel_sink)
-        self._kernel_sink = None
-
     # ----- exports -----
 
     def to_prometheus(self) -> str:
@@ -534,12 +627,14 @@ class Telemetry:
         """Span profile in the Chrome trace-event format (JSON object with
         ``traceEvents``; open in Perfetto / ``chrome://tracing``).
 
-        pid 1 ("scheduler") carries the engine's prefill and decode-step
-        spans on tid 0; pid 2 ("requests") gives each request its own tid
-        with a ``queue`` span (submit → admit) and a ``decode`` span
-        (admit → terminal) annotated with status/cause/tokens.
+        pid 1 ("scheduler") carries the recorded spans (``engine.step``
+        and the spans nested in it) on tid 0, each with its counts and step
+        as args; pid 2 ("requests") gives each request its own tid with a
+        ``queue`` span (submit → admit) and a ``decode`` span (admit →
+        terminal) annotated with status/cause/tokens.
         """
-        t0 = min((ev["ts"] for ev in self.events), default=0.0)
+        t0 = min([ev["ts"] for ev in self.events]
+                 + [rec.start for rec in self.spans], default=0.0)
         for tr in self.traces.values():
             t0 = min(t0, tr.submit_ts)
 
@@ -552,20 +647,15 @@ class Telemetry:
             {"ph": "M", "pid": 2, "name": "process_name",
              "args": {"name": "requests"}},
         ]
-        for ev in self.events:
-            if ev["event"] == "decode_step":
-                evs.append({"name": "decode_step", "ph": "X", "pid": 1,
-                            "tid": 0, "ts": us(ev["ts"] - ev["dur_s"]),
-                            "dur": ev["dur_s"] * 1e6,
-                            "args": {"step": ev["step"],
-                                     "active_rows": ev["active_rows"],
-                                     "queued": ev["queued"]}})
-            elif ev["event"] == "prefill":
-                evs.append({"name": "prefill", "ph": "X", "pid": 1,
-                            "tid": 0, "ts": us(ev["ts"] - ev["dur_s"]),
-                            "dur": ev["dur_s"] * 1e6,
-                            "args": {"wave": ev["wave"], "rows": ev["rows"],
-                                     "tpad": ev["tpad"]}})
+        for rec in self.spans:
+            if rec.end is None:
+                continue
+            args = dict(rec.counts)
+            if rec.step is not None:
+                args["step"] = rec.step
+            evs.append({"name": rec.name, "ph": "X", "pid": 1, "tid": 0,
+                        "ts": us(rec.start),
+                        "dur": (rec.end - rec.start) * 1e6, "args": args})
         for tr in self.traces.values():
             tid = tr.request_id
             evs.append({"ph": "M", "pid": 2, "tid": tid,

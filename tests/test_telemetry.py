@@ -1,8 +1,11 @@
 """Serving telemetry: histogram/percentile math under a fake clock, the
-golden JSONL trace schema, export well-formedness, and the on/off parity
-contract (telemetry must never change tokens or kernel launches)."""
+golden JSONL trace schema, export well-formedness, the span primitive (in
+memory and in the profiler's trace), and the on/off parity contract
+(telemetry and spans must never change tokens or kernel launches)."""
 
+import glob
 import json
+import os
 
 import jax
 import numpy as np
@@ -21,6 +24,7 @@ from repro.serving.telemetry import (
     ManualClock,
     MetricsRegistry,
     Telemetry,
+    span,
 )
 
 
@@ -122,6 +126,63 @@ def test_event_schema_enforced():
     assert len(tel.events) == 2
 
 
+def test_span_records_nesting_step_and_counts():
+    """Under a fake clock a span records its start and end, the span open
+    around it, the step of its step root, and its counts (given at entry
+    or set at the end); chrome_trace() exports the recorded spans."""
+    clock = ManualClock(start=1.0)
+    tel = Telemetry(clock=clock)
+    with span("engine.step", tel, step=7, rows=2) as root:
+        clock.advance(0.5)
+        with span("engine.decode", tel):
+            clock.advance(0.25)
+            with span("memory.swap_in", tel) as swap:
+                clock.advance(0.125)
+                swap.set(bytes=4096)
+        with span("engine.retire", tel) as retire:
+            retire.set(retired=1)
+        root.set(admitted=0)
+    with span("memory.acquire", tel, hit=1):     # outside any step
+        clock.advance(1.0)
+
+    got = [(r.name, r.start, r.end, r.parent, r.step, r.counts)
+           for r in tel.spans]
+    assert got == [
+        ("engine.step", 1.0, 1.875, None, 7, {"rows": 2, "admitted": 0}),
+        ("engine.decode", 1.5, 1.875, 0, 7, {}),
+        ("memory.swap_in", 1.75, 1.875, 1, 7, {"bytes": 4096}),
+        ("engine.retire", 1.875, 1.875, 0, 7, {"retired": 1}),
+        ("memory.acquire", 1.875, 2.875, None, None, {"hit": 1}),
+    ]
+    assert tel._open == []
+
+    doc = tel.chrome_trace()
+    xs = {ev["name"]: ev for ev in doc["traceEvents"]
+          if ev.get("ph") == "X" and ev["pid"] == 1}
+    assert set(xs) == {"engine.step", "engine.decode", "memory.swap_in",
+                       "engine.retire", "memory.acquire"}
+    assert xs["engine.step"]["ts"] == 0.0
+    assert xs["engine.step"]["dur"] == pytest.approx(875e3)
+    assert xs["engine.step"]["args"] == {"rows": 2, "admitted": 0, "step": 7}
+    assert xs["memory.swap_in"]["ts"] == pytest.approx(750e3)
+    assert xs["memory.swap_in"]["args"] == {"bytes": 4096, "step": 7}
+    assert xs["memory.acquire"]["args"] == {"hit": 1}
+
+
+def test_span_without_telemetry_records_nothing():
+    """With ``telemetry=None`` a span only opens the profiler annotation:
+    nothing is kept in memory, and an attached Telemetry elsewhere sees
+    none of it."""
+    tel = Telemetry(clock=ManualClock())
+    with span("engine.step", None, step=1) as root:
+        with span("engine.decode", None) as inner:
+            inner.set(rows=3)
+        root.set(admitted=1)
+    assert tel.spans == [] and tel._open == []
+    assert [ev for ev in tel.chrome_trace()["traceEvents"]
+            if ev.get("ph") == "X"] == []
+
+
 # ------------------------------------------------------------- engine-driven
 
 
@@ -163,6 +224,15 @@ def _run(tiny_model, tiny_store, telemetry=None, clock=None, n=5):
     return eng, done
 
 
+def _no_python():
+    """Profiler options without the Python tracer, which would record
+    every Python call of the interpreted kernels (millions of events on
+    the CPU); the spans are annotations and are recorded without it."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return opts
+
+
 GOLDEN_SCHEMA = {
     "submit": {"request_id", "adapter_id"},
     "admit": {"request_id", "adapter_id", "queue_wait_s", "wave", "row"},
@@ -183,10 +253,7 @@ def test_trace_schema_golden(tiny_model, tiny_store):
     assert {k: set(v) for k, v in EVENT_SCHEMA.items()} == GOLDEN_SCHEMA
 
     tel = Telemetry(clock=ManualClock())
-    try:
-        eng, done = _run(tiny_model, tiny_store, telemetry=tel)
-    finally:
-        tel.uninstall_kernel_counter()
+    eng, done = _run(tiny_model, tiny_store, telemetry=tel)
     assert len(done) == 5
 
     events = [json.loads(l) for l in tel.to_jsonl().splitlines()]
@@ -219,10 +286,7 @@ def test_histograms_under_fake_clock(tiny_model, tiny_store):
     view exposes their summaries."""
     clock = ManualClock()
     tel = Telemetry(clock=clock)
-    try:
-        eng, done = _run(tiny_model, tiny_store, telemetry=tel)
-    finally:
-        tel.uninstall_kernel_counter()
+    eng, done = _run(tiny_model, tiny_store, telemetry=tel)
     lat = tel.latency_summary()
     for name in ("serving_ttft_seconds", "serving_e2e_seconds",
                  "serving_queue_wait_seconds"):
@@ -269,10 +333,11 @@ def test_memory_stats_hit_rate_and_per_pool(tiny_model, tiny_store):
     assert set(st["prefetch"]) == {"hit", "staged", "failed", "no_slot"}
 
 
-def test_parity_tokens_and_launches(tiny_model, tiny_store):
-    """Telemetry is observation only: an instrumented engine must emit
-    token-identical output and issue zero extra pallas_call launches
-    compared to an uninstrumented one.
+def test_parity_tokens_and_launches(tiny_model, tiny_store, tmp_path):
+    """Telemetry and spans are observation only: an instrumented engine,
+    with its spans traced by the profiler or not, must emit token-identical
+    output and issue zero extra pallas_call launches compared to an
+    uninstrumented one.
 
     Trace-time launch counts of *consecutive* engine runs oscillate with
     period 2 (jit-cache retention across runs), independent of telemetry
@@ -289,26 +354,74 @@ def test_parity_tokens_and_launches(tiny_model, tiny_store):
 
     _run(tiny_model, tiny_store)                   # warm jit caches
     done_off, launches_off = measured(None)
-
     tel = Telemetry(clock=ManualClock())
-    try:
-        done_on, launches_on = measured(tel)
-    finally:
-        tel.uninstall_kernel_counter()
+    done_on, launches_on = measured(tel)
+    with jax.profiler.trace(str(tmp_path), profiler_options=_no_python()):
+        done_traced, launches_traced = measured(None)
 
     assert launches_on == launches_off, "telemetry changed kernel launches"
+    assert launches_traced == launches_off, "tracing changed kernel launches"
+    assert tel.spans, "the instrumented runs recorded no span"
     by_id_off = {r.request_id: r for r in done_off}
-    assert len(done_on) == len(done_off) == 5
-    for r in done_on:
+    assert len(done_on) == len(done_traced) == len(done_off) == 5
+    for r in done_on + done_traced:
         np.testing.assert_array_equal(r.output, by_id_off[r.request_id].output)
-    # the registry mirrored every launch recorded while installed (both
-    # instrumented runs), kernel-labeled
-    mirrored = {m.labels[0][1]: int(m.value)
-                for m in tel.registry.series("pallas_launches_total")}
-    total_on = {k: v for k, v in mirrored.items()}
-    assert set(total_on) == set(launches_on)
-    for k, v in launches_on.items():
-        assert total_on[k] >= v, (k, total_on, launches_on)
+
+
+PROGRAM_SPANS = ("engine.sweep", "engine.admit", "engine.select",
+                 "memory.acquire", "memory.swap_in", "engine.prefill",
+                 "engine.prefill.sync", "engine.scatter",
+                 "engine.decode.prep", "memory.prefetch", "engine.decode",
+                 "engine.decode.sync", "engine.retire")
+
+
+def test_spans_land_in_the_profiler_trace(tiny_model, tiny_store, tmp_path):
+    """Run the paged engine (3 adapters over 2 slots, so slot misses are
+    forced) under ``jax.profiler.trace`` and read the xplane back: every
+    span of the serving step is on the host plane, inside an
+    ``engine.step``; each swap-in lies inside an acquire or a prefetch; and
+    each decode's read-back follows its dispatch."""
+    with jax.profiler.trace(str(tmp_path), profiler_options=_no_python()):
+        _, done = _run(tiny_model, tiny_store)
+    assert len(done) == 5
+    files = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    assert len(files) == 1
+    data = jax.profiler.ProfileData.from_file(files[0])
+    spans = sorted(
+        (ev.start_ns, ev.start_ns + ev.duration_ns, ev.name, dict(ev.stats))
+        for plane in data.planes if plane.name.startswith("/host:")
+        for line in plane.lines for ev in line.events
+        if ev.name.startswith(("engine.", "memory.")))
+    names = {name for _, _, name, _ in spans}
+    assert set(PROGRAM_SPANS) | {"engine.step"} <= names, sorted(names)
+
+    def inside(s, names_):
+        return any(o[2] in names_ and o[0] <= s[0] and s[1] <= o[1]
+                   for o in spans if o is not s)
+
+    steps = [s for s in spans if s[2] == "engine.step"]
+    assert [s[3]["step_num"] for s in steps] == list(
+        range(steps[0][3]["step_num"], steps[0][3]["step_num"] + len(steps)))
+    for s in spans:
+        if s[2] != "engine.step":
+            assert inside(s, {"engine.step"}), s
+    swaps = [s for s in spans if s[2] == "memory.swap_in"]
+    for s in swaps:
+        assert inside(s, {"memory.acquire", "memory.prefetch"}), s
+        assert s[3]["bytes"] > 0
+    assert {s[3]["hit"] for s in spans if s[2] == "memory.acquire"} \
+        == {0, 1}
+    for st in steps:
+        within = [s for s in spans if st[0] <= s[0] and s[1] <= st[1]]
+        dec = [s for s in within if s[2] == "engine.decode"]
+        syncs = [s for s in within if s[2] == "engine.decode.sync"]
+        assert len(dec) == len(syncs) <= 1
+        if dec:
+            assert dec[0][1] <= syncs[0][0]
+    assert sum(s[3]["admitted"] for s in steps) == 5
+    assert sum(s[3]["retired"] for s in spans
+               if s[2] == "engine.retire") == 5
 
 
 def test_exports_parse_and_are_nonempty(tiny_model, tiny_store, tmp_path):
@@ -316,11 +429,8 @@ def test_exports_parse_and_are_nonempty(tiny_model, tiny_store, tmp_path):
     non-empty latency histograms and per-pool memory counters, parseable
     Chrome-trace JSON, and a JSONL log with one object per line."""
     tel = Telemetry(clock=ManualClock())
-    try:
-        eng, _ = _run(tiny_model, tiny_store, telemetry=tel)
-        eng.memory_stats()                         # mirror pool gauges
-    finally:
-        tel.uninstall_kernel_counter()
+    eng, _ = _run(tiny_model, tiny_store, telemetry=tel)
+    eng.memory_stats()                             # mirror pool gauges
 
     prom = tmp_path / "metrics.prom"
     trace = tmp_path / "trace.json"
@@ -333,8 +443,7 @@ def test_exports_parse_and_are_nonempty(tiny_model, tiny_store, tmp_path):
     for needle in ("serving_ttft_seconds_bucket", "serving_e2e_seconds_sum",
                    "serving_queue_wait_seconds_count",
                    "adapter_memory_hits_total{pool=",
-                   "adapter_memory_swap_ins_total{pool=",
-                   "pallas_launches_total{kernel="):
+                   "adapter_memory_swap_ins_total{pool="):
         assert needle in text, needle
     # exposition is line-structured: every non-comment line is "name value"
     for line in text.strip().splitlines():
@@ -344,7 +453,7 @@ def test_exports_parse_and_are_nonempty(tiny_model, tiny_store, tmp_path):
 
     doc = json.loads(trace.read_text())
     names = {ev.get("name") for ev in doc["traceEvents"]}
-    assert {"prefill", "decode_step", "queue", "decode"} <= names
+    assert {"engine.step", "engine.prefill", "queue", "decode"} <= names
     spans = [ev for ev in doc["traceEvents"] if ev.get("ph") == "X"]
     assert spans and all(ev["dur"] >= 0 and ev["ts"] >= 0 for ev in spans)
 
