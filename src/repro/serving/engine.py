@@ -634,7 +634,9 @@ class Request:
     deadline_ms: Optional[float] = None      # total budget (submit → done)
     ttft_deadline_ms: Optional[float] = None  # budget to the first token
     output: Optional[np.ndarray] = None
-    t_first: Optional[float] = None     # wall clock of first generated token
+    # clock at which the first generated token reached the host; continuous
+    # mode publishes it one read-back later, with the second token
+    t_first: Optional[float] = None
     t_submit: Optional[float] = None    # wall clock of submit (deadline base)
     status: RequestStatus = RequestStatus.PENDING
     error: Optional[RequestError] = None
@@ -647,12 +649,33 @@ class _Row:
     req: Request
     start: int                  # left-pad count (first real cache index)
     prompt_len: int
-    emitted: List[int]          # generated tokens so far (≥ 1 after prefill)
+    # tokens read back to the host so far; the newest one or two are still
+    # on the device while the row's next decode runs
+    emitted: List[int] = dataclasses.field(default_factory=list)
+    decoded: int = 0            # decode steps dispatched for this row
+    t_first: Optional[float] = None   # first token's arrival (unpublished)
     # NOTE the row does NOT cache its adapter's HBM slot id: the page is
     # pinned for the row's lifetime, but its GLOBAL id can shift (a pool
     # growth moves later pools' bases; a re-register with a new recipe
     # moves the page across pools), so decode re-reads memory.slot_of
     # every step.
+
+
+def _greedy(out):
+    """``(logits, caches)`` of a prefill or decode program to ``(tokens,
+    caches)``: each row's argmax at its last position, as int32, inside the
+    program, so that greedy tokens stay on the device."""
+    logits, caches = out
+    return jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32), caches
+
+
+def decode_step(decode, params, tokens, caches, pos, start):
+    """The continuous scheduler's decode program (``jit_decode_step``): the
+    model's decode program ``decode`` (a static argument) on each row's
+    newest token, ``tokens: (B,)`` on the device, then the greedy argmax.
+    Its output tokens are the next call's input, so the host never has to
+    hold them before it dispatches the next step."""
+    return _greedy(decode(params, tokens[:, None], caches, pos, start))
 
 
 class MultiLoRAEngine:
@@ -664,8 +687,11 @@ class MultiLoRAEngine:
     rows mid-decode (bursts of equal padded length are prefilled as one
     batch — left-padded only to a ``seg_tile`` multiple — and their caches
     scattered into the rows' slices in one call),
-    and retires rows the moment they hit ``max_new_tokens`` or ``eos_id``,
-    freeing the slot for the next admission. Per-row cache positions and
+    and retires rows in the step whose read-back delivers their last token
+    (``max_new_tokens`` or ``eos_id``), freeing the slot for the next
+    admission. Greedy tokens stay on the device: step n dispatches decode n
+    before it reads back decode n−1's tokens, so the host's work hides
+    under the device's. Per-row cache positions and
     validity masks make every row position-exact regardless of padding, so
     a request admitted mid-decode yields exactly the tokens of a solo run.
     Per-row adapter choice is a per-step rebuild of the SGMV segment ids
@@ -748,18 +774,29 @@ class MultiLoRAEngine:
         self._stalled_steps = 0
         self._rows: List[Optional[_Row]] = [None] * max_rows
         self._caches = None               # persistent (max_rows)-row caches
+        self._tok = None                  # (max_rows,) newest token per row
+        self._unread: List[int] = []      # rows whose newest token is unread
         self._memory = None               # paged adapter memory (lazy)
         self._dec_groups = None           # decode-retiled view of the pool
         self._dec_src = None              # the packed tree it was built from
+        # the static modes' programs return logits ...
         self._prefill = jax.jit(
             lambda p, b: model.prefill(p, b, cache_capacity))
         self._decode = jax.jit(model.decode_step)
-        # scatter a group's prefilled cache rows into the persistent batch
-        # cache: leaves are (layer_count, B, ...), so row indices land on
-        # axis 1 of every leaf
+        # ... continuous mode's return greedy tokens, left on the device.
+        # Its decode composes whatever program ``_decode`` holds, so a
+        # program swapped in there (the benchmark's planted faults,
+        # ``bench/faults.py``) reaches the served path too
+        self._prefill_greedy = jax.jit(
+            lambda p, b: _greedy(model.prefill(p, b, cache_capacity)))
+        self._decode_greedy = jax.jit(decode_step, static_argnums=0)
+        # scatter a group's prefilled cache rows and first tokens into the
+        # persistent batch: cache leaves are (layer_count, B, ...), so row
+        # indices land on axis 1 of every leaf
         self._scatter_rows = jax.jit(
-            lambda g, r, idx: jax.tree_util.tree_map(
-                lambda gg, rr: gg.at[:, idx].set(rr.astype(gg.dtype)), g, r))
+            lambda g, r, idx, tok, first: (jax.tree_util.tree_map(
+                lambda gg, rr: gg.at[:, idx].set(rr.astype(gg.dtype)), g, r),
+                tok.at[idx].set(first)))
 
     # ----- request lifecycle -----
 
@@ -1004,16 +1041,19 @@ class MultiLoRAEngine:
                    -(-len(req.prompt) // self.seg_tile) * self.seg_tile)
 
     def _admit_group(self, reqs: List[Request], rows: List[int],
-                     slots: List[int]) -> List[_Row]:
+                     slots: List[int]) -> Tuple[int, List[int], int, float]:
         """Prefill a group of same-padded-length requests as ONE batch
         (left-padded to a shared ``seg_tile`` multiple — the group's rows
         stay independent under the pad-mask contract) and scatter their
-        cache rows into the persistent batch in one call. Batching the
-        admissions amortizes per-dispatch overhead when requests arrive in
-        bursts; a lone arrival is simply a group of one. ``slots`` maps each
-        request to its adapter's (already pinned) HBM slot — the SGMV
-        segment id; a request whose page was faulted in this step is simply
-        queued behind the swap-in by dispatch order."""
+        cache rows and first tokens into the persistent batch in one call.
+        The first tokens stay on the device: the step's read-back brings
+        them to the host. Batching the admissions amortizes per-dispatch
+        overhead when requests arrive in bursts; a lone arrival is simply a
+        group of one. ``slots`` maps each request to its adapter's (already
+        pinned) HBM slot — the SGMV segment id; a request whose page was
+        faulted in this step is simply queued behind the swap-in by
+        dispatch order. Returns the group's wave, request ids, padded
+        length and dispatch time, for the prefill's telemetry."""
         tel = self.telemetry
         tpad = self._tpad(reqs[0])
         with span("engine.prefill", tel, rows=len(reqs), tpad=tpad):
@@ -1034,36 +1074,61 @@ class MultiLoRAEngine:
             pre = {"base": self.params["base"],
                    "lora": {"groups": packed["groups"],
                             "seg": jnp.asarray(np.repeat(sidx, tpad))}}
-            logits, grp_caches = self._prefill(
+            firsts, grp_caches = self._prefill_greedy(
                 pre, {"tokens": jnp.asarray(toks),
                       "start": jnp.asarray(starts)})
-        with span("engine.prefill.sync", tel):
-            firsts = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
-        now = self.clock()
-        if tel is not None:
-            tel.on_prefill(self._wave, [r.request_id for r in reqs],
-                           int(tpad), now - t_pre)
         with span("engine.scatter", tel, rows=len(rows)):
-            self._caches = self._scatter_rows(
+            self._caches, self._tok = self._scatter_rows(
                 self._caches, grp_caches,
-                jnp.asarray(np.asarray(rows, np.int32)))
-        out = []
+                jnp.asarray(np.asarray(rows, np.int32)), self._tok, firsts)
         for b, (req, row_idx) in enumerate(zip(reqs, rows)):
-            req.t_first = now
             req.status = RequestStatus.RUNNING
-            if self.telemetry is not None:
-                self.telemetry.on_first_token(req.request_id)
-            row = _Row(req=req, start=int(starts[b]),
-                       prompt_len=len(req.prompt), emitted=[int(firsts[b])])
-            self._rows[row_idx] = row
-            out.append(row)
-        return out
+            self._rows[row_idx] = _Row(req=req, start=int(starts[b]),
+                                       prompt_len=len(req.prompt))
+        self._unread.extend(rows)
+        return self._wave, [r.request_id for r in reqs], int(tpad), t_pre
 
     @staticmethod
     def _row_done(row: _Row) -> bool:
         r = row.req
         return (len(row.emitted) >= r.max_new_tokens
-                or (r.eos_id is not None and row.emitted[-1] == r.eos_id))
+                or (r.eos_id is not None and row.emitted[-1:] == [r.eos_id]))
+
+    def _needs_decode(self, row: _Row) -> bool:
+        """Whether a live row takes part in the next decode: it has decodes
+        left to dispatch (``max_new_tokens - 1``; the prefill gives the
+        first token) and has not read back its EOS."""
+        return (row.decoded < row.req.max_new_tokens - 1
+                and not self._row_done(row))
+
+    def _publish_first(self, row: _Row) -> None:
+        """Make the row's first-token stamp visible on its request (and in
+        telemetry) with the value it was taken at."""
+        req = row.req
+        if req.t_first is None and row.t_first is not None:
+            req.t_first = row.t_first
+            if self.telemetry is not None:
+                self.telemetry.on_first_token(req.request_id, row.t_first)
+
+    def _read_back(self, tok, rows: List[int]) -> float:
+        """Wait for the device token array ``tok`` on the host and give each
+        of ``rows`` its entry. A row's first token stamps its ``t_first``;
+        the stamp is published with the row's second token (or at its
+        retirement), so that a request's ``t_first`` becomes visible in the
+        same :meth:`step` return as its first two tokens. Returns the clock
+        at arrival (now, where there is nothing to read)."""
+        if not rows:
+            return self.clock()
+        vals = np.asarray(tok)
+        now = self.clock()
+        for i in rows:
+            row = self._rows[i]
+            row.emitted.append(int(vals[i]))
+            if len(row.emitted) == 1:
+                row.t_first = now
+            else:
+                self._publish_first(row)
+        return now
 
     def _retire(self, row_idx: int,
                 status: RequestStatus = RequestStatus.DONE,
@@ -1071,6 +1136,13 @@ class MultiLoRAEngine:
         row = self._rows[row_idx]
         self._rows[row_idx] = None
         self.memory.unpin(row.req.adapter_id)   # slot becomes evictable
+        if row_idx in self._unread:
+            # a decode dispatched before this row's EOS was read back: its
+            # token is never read
+            self._unread.remove(row_idx)
+            if self.telemetry is not None:
+                self.telemetry.on_discarded_token()
+        self._publish_first(row)
         # prefill always seeds one token; cap at the budget so degenerate
         # max_new_tokens <= 0 requests match the static modes' empty output.
         # Failure retirements keep the partial output produced so far.
@@ -1172,24 +1244,39 @@ class MultiLoRAEngine:
            are quarantined and their live rows retire FAILED (co-batched
            healthy rows are untouched — per-row seg ids isolate them);
            live rows past their total deadline retire TIMED_OUT with the
-           partial output.
+           partial output. A forced retirement first reads back the tokens
+           still on the device (a blocking read), so it keeps every token
+           decoded.
         1. **Admit**: move pending requests into free rows (FIFO with the
            failure contract — :meth:`_select_admissions`; bursts of equal
-           padded length prefill as one batch → cache-row scatter; a
-           request that finishes at admission frees its row for the next
-           pending one immediately). When every slot is pinned by live
-           rows the request stays pending — and if *nothing* is live to
-           ever unpin (externally pinned pool), ``stall_limit`` fruitless
-           steps reject the head with MemoryExhausted so admission can
-           never deadlock.
-        2. **Decode**: one step for the whole fixed-shape batch — per-row
-           cache positions/validity and per-row adapter **slot** ids as SGMV
-           seg ids; inactive rows run fully masked and are ignored. Before
-           the dispatch, next wave's pages are prefetched (swap-ins write
-           fresh buffers, so the copies overlap the in-flight decode).
-        3. **Retire**: rows hitting ``max_new_tokens``/``eos_id`` free their
-           batch row, unpin their adapter slot, and their request (with
-           ``output`` set, status DONE) is returned.
+           padded length prefill as one batch → scatter of cache rows and
+           first tokens, which stay on the device). When every slot is
+           pinned by live rows the request stays pending — and if
+           *nothing* is live to ever unpin (externally pinned pool),
+           ``stall_limit`` fruitless steps reject the head with
+           MemoryExhausted so admission can never deadlock.
+        2. **Prep** and **prefetch**: per-row cache positions/validity and
+           adapter **slot** ids as SGMV seg ids — all known on the host —
+           for the rows with decodes left; other rows run fully masked.
+           Next wave's pages are prefetched (swap-ins write fresh buffers,
+           so the copies overlap the decode).
+        3. **Decode** n: one greedy step for the whole fixed-shape batch
+           (``jit_decode_step``), its input the device token array the
+           previous decode returned, its argmax left on the device.
+        4. **Read back** decode n−1's tokens and this step's first tokens
+           (``engine.decode.sync``) while decode n runs: the host's
+           read-back, and the next step's prep and dispatch, hide under the
+           device's work.
+        5. **Retire**: rows whose read-back delivered their last token
+           (``max_new_tokens``, or ``eos_id`` — a decode already in flight
+           for such a row is discarded) free their batch row, unpin their
+           adapter slot, and their request (with ``output`` set, status
+           DONE) is returned.
+
+        So a request's tokens reach the host one step after they are
+        decoded. Its ``t_first`` is published in the ``step()`` return that
+        delivers its second token, holding the clock at which its first
+        arrived; every later step delivers one token per live row.
 
         Returns the requests that reached a terminal state during this
         step, completion-ordered. The step and each of its phases run under
@@ -1239,31 +1326,48 @@ class MultiLoRAEngine:
                 aid, ver = mgr.poisoned.popitem()
                 if self.store.version(aid) == ver:
                     self.quarantined[aid] = ver
+            forced = []
             for i in range(self.max_rows):
                 row = self._rows[i]
                 if row is None:
                     continue
-                if self._is_quarantined(row.req.adapter_id):
-                    finished.append(self._retire(
-                        i, RequestStatus.FAILED, PoisonedAdapter(
-                            f"request {row.req.request_id}: adapter "
-                            f"{row.req.adapter_id!r} was quarantined "
-                            f"mid-decode", adapter_id=row.req.adapter_id)))
-                    continue
                 req = row.req
-                if (req.deadline_ms is not None and req.t_submit is not None
+                if self._is_quarantined(req.adapter_id):
+                    forced.append((i, RequestStatus.FAILED, PoisonedAdapter(
+                        f"request {req.request_id}: adapter "
+                        f"{req.adapter_id!r} was quarantined mid-decode",
+                        adapter_id=req.adapter_id)))
+                elif (req.deadline_ms is not None
+                        and req.t_submit is not None
                         and (now - req.t_submit) * 1e3 > req.deadline_ms):
-                    finished.append(self._retire(
-                        i, RequestStatus.TIMED_OUT, DeadlineExceeded(
-                            f"request {req.request_id}: total deadline "
-                            f"({req.deadline_ms:g} ms) expired mid-decode",
-                            adapter_id=req.adapter_id)))
+                    forced.append((i, RequestStatus.TIMED_OUT,
+                                   DeadlineExceeded(
+                        f"request {req.request_id}: total deadline "
+                        f"({req.deadline_ms:g} ms) expired mid-decode",
+                        adapter_id=req.adapter_id)))
+            if forced and self._unread:
+                # keep every token decoded: read the last decode's tokens
+                # before the rows go (blocking, and rare)
+                self._read_back(self._tok, self._unread)
+                self._unread = []
+            for i, status, err in forced:
+                # a row whose last token that read delivered ends as it
+                # would have at its own read-back
+                if self._row_done(self._rows[i]):
+                    finished.append(self._retire(i))
+                else:
+                    finished.append(self._retire(i, status, err))
             sweep.set(expired=len(finished) - n_done)
         if self._caches is None:
             self._caches = self.model.init_cache(self.max_rows, self.capacity)
+            self._tok = jnp.zeros((self.max_rows,), jnp.int32)
+        # the previous decode's tokens, still on the device: this step's
+        # decode is dispatched before they are read
+        carried = bool(self._unread)
         # admit FIFO, batching the leading run of equal padded lengths into
-        # one prefill; retiring-at-admission frees rows for the next group
+        # one prefill
         admitted = 0
+        groups = []
         while self.pending:
             free = [i for i in range(self.max_rows) if self._rows[i] is None]
             if not free:
@@ -1281,13 +1385,10 @@ class MultiLoRAEngine:
                 # acquires: a later acquire may grow a pool and shift
                 # earlier ids
                 slots = [mgr.slot_of(r.adapter_id) for r in group]
-                rows = free[:len(group)]
-                for row_idx, row in zip(
-                        rows, self._admit_group(group, rows, slots)):
-                    if self._row_done(row):
-                        finished.append(self._retire(row_idx))
-        active = [i for i in range(self.max_rows) if self._rows[i] is not None]
-        if not active:
+                groups.append(self._admit_group(
+                    group, free[:len(group)], slots))
+        live = [i for i in range(self.max_rows) if self._rows[i] is not None]
+        if not live:
             if self.pending and not admitted and not finished:
                 # nothing live to ever unpin a slot (externally pinned
                 # pool): bounded patience, then shed the head so run()
@@ -1307,59 +1408,70 @@ class MultiLoRAEngine:
             self._prefetch_upcoming()
             return 0, admitted
         self._stalled_steps = 0
-        with span("engine.decode.prep", tel) as prep:
-            toks = np.zeros((self.max_rows, 1), np.int32)
-            pos = np.zeros((self.max_rows,), np.int32)
-            # inactive rows: valid_start == capacity masks every cache
-            # slot, so they decode garbage finitely (NEG_INF masking) and
-            # touch nothing.
-            start = np.full((self.max_rows,), self.capacity, np.int32)
-            seg = np.zeros((self.max_rows,), np.int32)
-            for i in active:
-                row = self._rows[i]
-                toks[i, 0] = row.emitted[-1]
-                pos[i] = row.start + row.prompt_len + len(row.emitted) - 1
-                start[i] = row.start
-                # seg ids ARE (global) slot ids: the page is pinned at
-                # admission, but its global id can shift when an earlier
-                # recipe pool grows — read the current id every step (must
-                # happen BEFORE the prefetch below, which may grow pools)
-                seg[i] = mgr.slot_of(row.req.adapter_id)
-            packed = mgr.serving_tree()
-            # the tile_t=1 decode view of the slot pool is rebuilt only when
-            # the pool changed (serving_tree caches until a swap-in/growth
-            # dirties it, so object identity is the change signal; keeping
-            # the strong reference in _dec_src is what makes identity a
-            # safe key)
-            retiled = self._dec_src is not packed
-            if retiled:
-                self._dec_groups = retile_packed(packed, 1)["groups"]
-                self._dec_src = packed
-            dec = {"base": self.params["base"],
-                   "lora": {"groups": self._dec_groups,
-                            "seg": jnp.asarray(seg)}}
-            prep.set(retiled=int(retiled))
+        active = [i for i in live if self._needs_decode(self._rows[i])]
+        # what this step reads back: the tokens the previous decode left
+        # and the first tokens scattered in since
+        read, unread = self._tok, self._unread
+        self._unread = []
+        if active:
+            with span("engine.decode.prep", tel) as prep:
+                pos = np.zeros((self.max_rows,), np.int32)
+                # rows not decoding: valid_start == capacity masks every
+                # cache slot, so they decode garbage finitely (NEG_INF
+                # masking) that nothing reads
+                start = np.full((self.max_rows,), self.capacity, np.int32)
+                seg = np.zeros((self.max_rows,), np.int32)
+                for i in active:
+                    row = self._rows[i]
+                    pos[i] = row.start + row.prompt_len + row.decoded
+                    row.decoded += 1
+                    start[i] = row.start
+                    # seg ids ARE (global) slot ids: the page is pinned at
+                    # admission, but its global id can shift when an
+                    # earlier recipe pool grows — read the current id every
+                    # step (must happen BEFORE the prefetch below, which
+                    # may grow pools)
+                    seg[i] = mgr.slot_of(row.req.adapter_id)
+                packed = mgr.serving_tree()
+                # the tile_t=1 decode view of the slot pool is rebuilt only
+                # when the pool changed (serving_tree caches until a
+                # swap-in/growth dirties it, so object identity is the
+                # change signal; keeping the strong reference in _dec_src
+                # is what makes identity a safe key)
+                retiled = self._dec_src is not packed
+                if retiled:
+                    self._dec_groups = retile_packed(packed, 1)["groups"]
+                    self._dec_src = packed
+                dec = {"base": self.params["base"],
+                       "lora": {"groups": self._dec_groups,
+                                "seg": jnp.asarray(seg)}}
+                prep.set(retiled=int(retiled))
         # stage next wave AFTER building this step's view, BEFORE dispatch:
         # the swap-in copies and the decode below have no data dependency
         self._prefetch_upcoming()
-        with span("engine.decode", tel):
-            logits, self._caches = self._decode(
-                dec, jnp.asarray(toks), self._caches,
-                jnp.asarray(pos), jnp.asarray(start))
-        with span("engine.decode.sync", tel):
-            nxt = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
-        self._step_count += 1
+        if active:
+            with span("engine.decode", tel, overlapped=int(carried)):
+                self._tok, self._caches = self._decode_greedy(
+                    self._decode, dec, read, self._caches,
+                    jnp.asarray(pos), jnp.asarray(start))
+            self._unread = active
+            self._step_count += 1
+        with span("engine.decode.sync", tel, rows=len(unread)):
+            t_read = self._read_back(read, unread)
         if tel is not None:
-            tel.on_decode_step(
-                self._step_count, self.clock() - t_step, len(active),
-                self.max_rows, len(self.pending),
-                request_ids=[self._rows[i].req.request_id for i in active])
+            for wave, ids, tpad, t_pre in groups:
+                tel.on_prefill(wave, ids, tpad, t_read - t_pre)
+            if active:
+                tel.on_decode_step(
+                    self._step_count, self.clock() - t_step, len(active),
+                    self.max_rows, len(self.pending),
+                    request_ids=[self._rows[i].req.request_id
+                                 for i in active],
+                    overlapped=carried)
         with span("engine.retire", tel) as retire:
             n_done = len(finished)
-            for i in active:
-                row = self._rows[i]
-                row.emitted.append(int(nxt[i]))
-                if self._row_done(row):
+            for i in live:
+                if self._row_done(self._rows[i]):
                     finished.append(self._retire(i))
             retire.set(retired=len(finished) - n_done)
         return len(active), admitted

@@ -548,24 +548,35 @@ class Telemetry:
             help="requests per admission wave").observe(len(request_ids))
         self.registry.histogram(
             "serving_prefill_seconds",
-            help="admission prefill latency: dispatch, then the wait for "
-                 "the first tokens' read-back").observe(dur_s)
+            help="admission prefill latency: dispatch to the first tokens' "
+                 "arrival on the host, read back with the step's decode "
+                 "tokens").observe(dur_s)
 
-    def on_first_token(self, request_id: int) -> None:
+    def on_first_token(self, request_id: int,
+                       ts: Optional[float] = None) -> None:
+        """The request's first token reached the host at ``ts`` (default:
+        now); the engine reports it one read-back later."""
         tr = self.traces.get(request_id)
         if tr is None or tr.first_token_ts is not None:
             return
-        tr.first_token_ts = self.now()
+        tr.first_token_ts = self.now() if ts is None else ts
         self.event("first_token", request_id=request_id, ttft_s=tr.ttft_s)
 
     def on_decode_step(self, step: int, dur_s: float, active_rows: int,
                        max_rows: int, queued: int,
-                       request_ids: Iterable[int] = ()) -> None:
+                       request_ids: Iterable[int] = (),
+                       overlapped: bool = False) -> None:
+        """One decode step dispatched; ``overlapped``: while the previous
+        step's tokens were still on the device."""
         self.event("decode_step", step=step, dur_s=dur_s,
                    active_rows=active_rows, max_rows=max_rows, queued=queued)
         self.registry.counter(
             "serving_decode_steps_total",
             help="scheduler decode steps dispatched").inc()
+        self.registry.counter(
+            "serving_decode_overlapped_total",
+            help="decode steps dispatched before the previous step's tokens "
+                 "were read back").inc(int(overlapped))
         self.registry.histogram(
             "serving_step_seconds",
             help="scheduler step latency (sweep+admit+decode)"
@@ -580,6 +591,14 @@ class Telemetry:
             tr = self.traces.get(rid)
             if tr is not None:
                 tr.decode_steps += 1
+
+    def on_discarded_token(self) -> None:
+        """A row retired with a decode of its own still in flight (past its
+        EOS or a forced retirement): that token is never read."""
+        self.registry.counter(
+            "serving_decode_discarded_tokens_total",
+            help="tokens decoded past a row's EOS or forced retirement, "
+                 "never read").inc()
 
     def on_retire(self, request_id: int, status: str, cause: str,
                   tokens: int) -> None:

@@ -21,6 +21,7 @@ from repro.serving.engine import (
     quantize_adapter_tree,
 )
 from repro.serving.faults import RequestStatus, UnknownAdapter
+from repro.serving.telemetry import Telemetry
 
 
 @pytest.fixture(scope="module")
@@ -344,6 +345,152 @@ def test_mid_decode_register_keeps_row_adapters(tiny_model, served_store,
     while cont_engine.pending or cont_engine.active_rows:
         done += cont_engine.step()
     np.testing.assert_array_equal(done[-1].output, solo)
+
+
+def _free_outputs(cfg, model, params, store):
+    """The scheduler requests' outputs with no EOS (solo-exact)."""
+    eng = MultiLoRAEngine(model, params, store, cache_capacity=64,
+                          max_rows=2)
+    for r in _sched_requests(cfg):
+        eng.submit(r)
+    return {r.request_id: r.output for r in eng.run()}
+
+
+def test_overlapped_decode_matches_static_with_eos_tail(tiny_model,
+                                                        served_store):
+    """Step n dispatches decode n before it reads back decode n−1's
+    tokens. With one row hitting its EOS mid-decode (the decode already in
+    flight for it is discarded) and short rows finishing beside long ones,
+    the continuous run is token-for-token the static packed one."""
+    cfg, model, params = tiny_model
+    free = _free_outputs(cfg, model, params, served_store)
+    eos = int(free[0][2])
+
+    def reqs():
+        rs = _sched_requests(cfg)
+        rs[0] = dataclasses.replace(rs[0], eos_id=eos)
+        return rs
+
+    tel = Telemetry()
+    eng = MultiLoRAEngine(model, params, served_store, cache_capacity=64,
+                          max_rows=2, telemetry=tel)
+    for r in reqs():
+        eng.submit(r)
+    cont = {r.request_id: r.output for r in eng.run()}
+    static = MultiLoRAEngine(model, params, served_store, cache_capacity=64)
+    for r in reqs():
+        static.submit(r)
+    ref = {r.request_id: r.output for r in static.run(mode="packed")}
+    assert cont.keys() == ref.keys()
+    for rid in ref:
+        np.testing.assert_array_equal(cont[rid], ref[rid])
+    assert cont[0].size <= 3 < 6                  # stopped at its EOS
+    assert cont[0][-1] == eos
+    assert tel.registry.value("serving_decode_discarded_tokens_total") == 1
+
+
+def test_read_back_contract_two_tokens_then_one_per_step(tiny_model,
+                                                         served_store):
+    """What a caller that stamps tokens per ``step()`` relies on: a
+    request's ``t_first`` becomes visible in the ``step()`` return that
+    holds its first two tokens, and carries the clock of an earlier step's
+    read-back; every later step adds one token per live request; a request
+    is returned in the step that delivered its last token (budget or
+    EOS). So one stamp for ``t_first`` and one per step return count its
+    output exactly, arrivals mid-decode included."""
+    cfg, model, params = tiny_model
+    free = _free_outputs(cfg, model, params, served_store)
+    # an EOS first met at token k >= 2: the request ends mid-decode, after
+    # t_first has been published
+    k = next(k for k in range(2, 6) if free[2][k] not in free[2][:k])
+    reqs = _sched_requests(cfg)
+    reqs[2] = dataclasses.replace(reqs[2], eos_id=int(free[2][k]))
+    eng = MultiLoRAEngine(model, params, served_store, cache_capacity=64,
+                          max_rows=2)
+    for r in reqs[:2]:
+        eng.submit(r)
+    waiting, stamps, steps = list(reqs), {}, 0
+    while eng.pending or eng.active_rows or steps < 2:
+        t_call = eng.clock()
+        fin = eng.step()
+        steps += 1
+        if steps == 2:
+            for r in reqs[2:]:                    # arrive mid-decode
+                eng.submit(r)
+        for r in [r for r in waiting if r.t_first is not None]:
+            waiting.remove(r)
+            assert r.t_first < t_call             # stamped a step earlier
+            row = next((x for x in eng._rows
+                        if x is not None and x.req is r), None)
+            assert row is None or len(row.emitted) == 2
+            stamps[r.request_id] = 1
+        for rid in stamps:
+            if not reqs[rid].status.terminal or reqs[rid] in fin:
+                stamps[rid] += 1
+        for r in fin:
+            assert r.status is RequestStatus.DONE
+            assert len(r.output) == stamps[r.request_id], r.request_id
+    assert not waiting and sorted(stamps) == [0, 1, 2, 3]
+    assert reqs[2].output.size == k + 1
+
+
+def test_overlapped_counter_is_decodes_less_restarts(tiny_model,
+                                                     served_store):
+    """``serving_decode_overlapped_total`` counts the decodes dispatched
+    while the previous step's tokens were still on the device: every
+    decode step but those that restart decoding after a step without one
+    (the first, and the first after an idle spell)."""
+    cfg, model, params = tiny_model
+    tel = Telemetry()
+    eng = MultiLoRAEngine(model, params, served_store, cache_capacity=64,
+                          max_rows=2, telemetry=tel)
+    decodes = []
+    for burst in range(2):                        # idle between the bursts
+        for r in _sched_requests(cfg):
+            r.request_id += 10 * burst
+            eng.submit(r)
+        while eng.pending or eng.active_rows:
+            before = eng.stats()["decode_steps"]
+            eng.step()
+            decodes.append(eng.stats()["decode_steps"] - before)
+        decodes.append(0)
+    restarts = sum(1 for prev, d in zip([0] + decodes, decodes)
+                   if d and not prev)
+    reg = tel.registry
+    assert restarts >= 2 and sum(decodes) > restarts
+    assert reg.value("serving_decode_steps_total") == sum(decodes)
+    assert reg.value("serving_decode_overlapped_total") \
+        == sum(decodes) - restarts
+    overlapped = [s.counts["overlapped"] for s in tel.spans
+                  if s.name == "engine.decode"]
+    assert len(overlapped) == sum(decodes)
+    assert sum(overlapped) == sum(decodes) - restarts
+
+
+def test_discarded_tokens_count_the_eos_tail(tiny_model, served_store):
+    """``serving_decode_discarded_tokens_total`` counts one token for each
+    row that reads back its EOS with decodes still left in its budget (the
+    decode dispatched ahead for it), and none for a row whose EOS is its
+    last budgeted token."""
+    cfg, model, params = tiny_model
+    free = _free_outputs(cfg, model, params, served_store)
+    reqs = [dataclasses.replace(r, eos_id=int(free[r.request_id][1]))
+            for r in _sched_requests(cfg)]
+    cuts = {r.request_id: int(np.nonzero(free[r.request_id]
+                                          == r.eos_id)[0][0])
+            for r in reqs}
+    tail = sum(cuts[r.request_id] < r.max_new_tokens - 1 for r in reqs)
+    tel = Telemetry()
+    eng = MultiLoRAEngine(model, params, served_store, cache_capacity=64,
+                          max_rows=2, telemetry=tel)
+    for r in reqs:
+        eng.submit(r)
+    done = {r.request_id: r.output for r in eng.run()}
+    for rid, cut in cuts.items():
+        np.testing.assert_array_equal(done[rid], free[rid][: cut + 1])
+    assert tail >= 2                              # both long rows stop early
+    assert tel.registry.value("serving_decode_discarded_tokens_total") \
+        == tail
 
 
 def test_left_padded_batch_matches_unpadded_serving(tiny_model, served_store):

@@ -370,7 +370,7 @@ def test_parity_tokens_and_launches(tiny_model, tiny_store, tmp_path):
 
 PROGRAM_SPANS = ("engine.sweep", "engine.admit", "engine.select",
                  "memory.acquire", "memory.swap_in", "engine.prefill",
-                 "engine.prefill.sync", "engine.scatter",
+                 "engine.scatter",
                  "engine.decode.prep", "memory.prefetch", "engine.decode",
                  "engine.decode.sync", "engine.retire")
 
@@ -380,7 +380,8 @@ def test_spans_land_in_the_profiler_trace(tiny_model, tiny_store, tmp_path):
     forced) under ``jax.profiler.trace`` and read the xplane back: every
     span of the serving step is on the host plane, inside an
     ``engine.step``; each swap-in lies inside an acquire or a prefetch; and
-    each decode's read-back follows its dispatch."""
+    a step's read-back follows its decode's dispatch (the last steps read
+    back without dispatching)."""
     with jax.profiler.trace(str(tmp_path), profiler_options=_no_python()):
         _, done = _run(tiny_model, tiny_store)
     assert len(done) == 5
@@ -416,7 +417,7 @@ def test_spans_land_in_the_profiler_trace(tiny_model, tiny_store, tmp_path):
         within = [s for s in spans if st[0] <= s[0] and s[1] <= st[1]]
         dec = [s for s in within if s[2] == "engine.decode"]
         syncs = [s for s in within if s[2] == "engine.decode.sync"]
-        assert len(dec) == len(syncs) <= 1
+        assert len(dec) <= len(syncs) <= 1
         if dec:
             assert dec[0][1] <= syncs[0][0]
     assert sum(s[3]["admitted"] for s in steps) == 5
