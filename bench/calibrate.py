@@ -29,6 +29,7 @@ sys.path.insert(1, os.path.join(os.path.dirname(HERE), "src"))
 import numpy as np  # noqa: E402
 
 import harness  # noqa: E402
+import layout  # noqa: E402
 
 
 def _seqs(window, seed, cell):
@@ -66,7 +67,7 @@ def main(argv=None) -> int:
         print("calibrate: needs a TPU", file=sys.stderr)
         return 3
     watch = harness.compile_setup(jax)
-    ref = harness._module(harness.CODE / "references" /
+    ref = layout.load(harness.CODE / "references" /
                           f"{cell.mc['reference']}.py")
     recipe = cell.traffic["fleet"]["recipe"]
     shape = harness.reference_shape(cell.traffic)

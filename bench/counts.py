@@ -9,32 +9,29 @@ from __future__ import annotations
 
 from typing import Sequence
 
-LORA_PATHS = ("wq", "wk", "wv", "wo", "wg", "wu", "wd")
+import layout
 
 
-def path_shapes(mc: dict) -> dict:
-    """``{path: (in_features, out_features)}`` of every LoRA-targeted linear
-    of one decoder layer (attention projections and the SwiGLU FFN)."""
-    d, dh = mc["d_model"], mc["head_dim"]
-    q, kv, f = mc["n_heads"] * dh, mc["n_kv_heads"] * dh, mc["d_ff"]
-    return {"wq": (d, q), "wk": (d, kv), "wv": (d, kv), "wo": (q, d),
-            "wg": (d, f), "wu": (d, f), "wd": (f, d)}
+def linear_flops(k: int, m: int, rank: int) -> int:
+    """FLOPs one token costs in a ``k → m`` linear with a rank-``rank``
+    adapter on it (``rank`` 0: none)."""
+    return 2 * k * m + 2 * rank * (k + m)
 
 
 def dense_flops_per_token(mc: dict) -> int:
     """Matrix-multiply FLOPs one token costs outside attention's score and
-    value products: every layer's projections, its rank-``lora_rank``
-    adapter on each of them, and the output head."""
-    r = mc["lora_rank"]
-    per_layer = sum(2 * i * o + 2 * r * (i + o)
-                    for i, o in path_shapes(mc).values())
-    return mc["n_layers"] * per_layer + 2 * mc["vocab"] * mc["d_model"]
+    value products: every layer's kinds (``blocks/<kind>.py``), each with its
+    adapters, and the output head."""
+    per_layers = sum(g.count * mod.flops_per_token(mc)
+                     for _, g, _, _, mod in layout.roles(mc))
+    return per_layers + 2 * mc["vocab"] * mc["d_model"]
 
 
 def attention_flops(mc: dict, keys: int) -> int:
     """Score and value FLOPs of one query token attending to ``keys`` keys,
     over all layers."""
-    return 4 * mc["n_layers"] * mc["n_heads"] * mc["head_dim"] * keys
+    return keys * sum(g.count * mod.attention_flops_per_key(mc)
+                      for _, g, _, _, mod in layout.roles(mc))
 
 
 def decode_step_flops(mc: dict, rows: int, keys: int) -> int:
@@ -82,14 +79,19 @@ def sgmv_call(t: int, k: int, m: int, rank: int, bits_hi: int, group: int,
 def sgmv_decode_step(mc: dict, rows: int, n_adapters: int, bits_hi: int,
                      group: int) -> tuple:
     """``(flops, bytes)`` of every SGMV call of one decode step: one call per
-    LoRA path per layer, ``rows`` rows each (``tile_t = 1``)."""
+    LoRA-targeted linear per layer, ``rows`` rows each (``tile_t = 1``). A
+    per-expert adapter's calls run over the expert dispatch's rows, which
+    only its kind knows: such a linear is refused here."""
     flops = nbytes = 0
-    for k, m in path_shapes(mc).values():
-        f, b = sgmv_call(rows, k, m, mc["lora_rank"], bits_hi, group,
+    for lin in layout.linears(mc):
+        if lin.lead:
+            raise ValueError(f"{lin.path}: per-expert SGMV calls are not "
+                             "counted here")
+        f, b = sgmv_call(rows, lin.k, lin.m, mc["lora_rank"], bits_hi, group,
                          n_adapters)
-        flops += f
-        nbytes += b
-    return mc["n_layers"] * flops, mc["n_layers"] * nbytes
+        flops += lin.count * f
+        nbytes += lin.count * b
+    return flops, nbytes
 
 
 def roofline_seconds(flops: float, nbytes: float, peak: dict) -> tuple:

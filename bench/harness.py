@@ -4,8 +4,9 @@ The entry the window drives is ``MultiLoRAEngine.step()`` in continuous
 mode, fed by ``MultiLoRAEngine.submit`` from ``serve_loop.run_window``.
 Everything a cell is made of is found by name from ``BENCHMARK.json``:
 its configuration file, ``traffic/<mix>.json``, ``limits/<cell>.json``,
-``references/<reference>.py`` and ``metrics/<metric>.py`` (one reader per
-metric, ``read(run) -> float | None``).
+``references/<reference>.py``, ``metrics/<metric>.py`` (one reader per
+metric, ``read(run) -> float | None``) and, for each kind of layer the
+configuration's ``blocks`` name, ``blocks/<kind>.py`` (``layout.py``).
 
 ``BENCH_REHEARSAL=1`` in the environment is the CPU rehearsal of the
 benchmark's own tests: it applies each file's ``rehearsal`` sizes, skips
@@ -19,16 +20,18 @@ from __future__ import annotations
 import collections
 import dataclasses
 import gc
-import importlib.util
 import json
 import os
 import shutil
 import sys
 import time
 import types
+import typing
 from pathlib import Path
 
 import numpy as np
+
+import layout
 
 CODE = Path(__file__).resolve().parent
 SETUP_PROMPT_NEW = 2          # warm-up requests: one prefill + one decode
@@ -47,14 +50,6 @@ def log(msg: str) -> None:
 def _load_json(path: Path) -> dict:
     with open(path) as f:
         return json.load(f)
-
-
-def _module(path: Path):
-    spec = importlib.util.spec_from_file_location(
-        f"bench_{path.parent.name}_{path.stem.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def _rehearsal(d: dict, on: bool) -> dict:
@@ -131,41 +126,41 @@ class CompileWatch:
 
 def _fleet(cell: Cell, recipe, seed: int):
     """The fleet as the program's ``QuantizedAdapter``s, made from the
-    benchmark's seeded codes (``weights.fleet_codes``)."""
+    benchmark's seeded codes (``weights.fleet_codes``): per LoRA-targeted
+    linear, one entry per layer and lead index (expert), in the row-major
+    order in which the program flattens a stacked ``(L, *lead, r, in)``
+    factor."""
     import jax
 
     import weights
-    from counts import path_shapes
     from repro.core.loraquant import QuantizedLoRA
     from repro.core.quant import QuantizedTensor
-    from repro.serving.engine import QuantizedAdapter, iter_lora_linears
+    from repro.serving.engine import QuantizedAdapter
 
     mc, fleet = cell.mc, cell.traffic["fleet"]
     codes = jax.device_get(weights.fleet_codes(
         mc, fleet["recipe"], seed, range(fleet["adapters"])))
     template = weights.lora_template(mc)
-    paths = {p.rsplit("/", 1)[1]: p for p, _ in iter_lora_linears(template)}
-    shapes = path_shapes(mc)
     r, bits, group = mc["lora_rank"], recipe.bits_high, recipe.group_size
     out = []
     for i in range(fleet["adapters"]):
         entries = {}
-        for name, path in paths.items():
-            f = codes[name]
-            k, m = shapes[name]
+        for lin in layout.linears(mc):
+            f = codes[lin.path]
             layers = []
-            for layer in range(mc["n_layers"]):
-                h = int(f["h"][i, layer])
+            for at in np.ndindex((lin.count,) + lin.lead):
+                at = (i,) + at
+                h = int(f["h"][at])
 
                 def qt(side, hi, dim):
-                    rows = slice(0, h) if hi else slice(h, r)
+                    rows = at + (slice(0, h) if hi else slice(h, r),)
                     pre = f"{side}{'h' if hi else 'l'}_"
-                    scale = f[pre + "scale"][i, layer, rows]
-                    zero = (f[pre + "zero"][i, layer, rows] if hi
+                    scale = f[pre + "scale"][rows]
+                    zero = (f[pre + "zero"][rows] if hi
                             else np.zeros(scale.shape, np.int32))
                     n = scale.shape[0]
                     return QuantizedTensor(
-                        codes=f[pre + "codes"][i, layer, rows], scale=scale,
+                        codes=f[pre + "codes"][rows], scale=scale,
                         zero=zero, bits=bits if hi else 1,
                         group_size=min(group, dim),
                         axis=1 if side == "a" else 0,
@@ -173,10 +168,10 @@ def _fleet(cell: Cell, recipe, seed: int):
                         mode="rtn" if hi else "binary")
 
                 layers.append(QuantizedLoRA(
-                    b_high=qt("b", True, m), a_high=qt("a", True, k),
-                    b_low=qt("b", False, m), a_low=qt("a", False, k),
+                    b_high=qt("b", True, lin.m), a_high=qt("a", True, lin.k),
+                    b_low=qt("b", False, lin.m), a_low=qt("a", False, lin.k),
                     h=h, rank=r, config=recipe))
-            entries[path] = layers
+            entries[lin.path] = layers
         out.append(QuantizedAdapter(entries=entries, template=template,
                                     recipe=recipe))
     return out
@@ -196,6 +191,35 @@ def paging_order(cell: Cell, window_seeds, seconds: float) -> list:
     return sorted(counts, key=lambda a: (counts[a], a))
 
 
+def _typed(tp, value):
+    """``value`` as read from a configuration file, as the program's type
+    ``tp``: a dataclass from an object, a tuple from a list, each part
+    typed in turn; anything else as it is."""
+    if dataclasses.is_dataclass(tp) and isinstance(value, dict):
+        hints = typing.get_type_hints(tp)
+        return tp(**{k: _typed(hints[k], v) for k, v in value.items()})
+    args = [a for a in typing.get_args(tp) if a is not type(None)]
+    if typing.get_origin(tp) is tuple and isinstance(value, list):
+        return tuple(_typed(args[0], v) for v in value)
+    if typing.get_origin(tp) is typing.Union and len(args) == 1:
+        return _typed(args[0], value)
+    return value
+
+
+def model_config(mc: dict):
+    """The program's ``ModelConfig`` for a configuration file: its keys
+    that name a ``ModelConfig`` field, nested groups (``blocks``, ``mla``,
+    ``moe``) typed from the field's annotation."""
+    import weights
+    from repro.configs.base import ModelConfig
+
+    layout.groups(mc)           # the groups hold n_layers layers
+    hints = typing.get_type_hints(ModelConfig)
+    return ModelConfig(**{k: _typed(hints[k], v) for k, v in mc.items()
+                          if k in hints and k != "dtype"},
+                       dtype=weights.dtype_of(mc))
+
+
 def setup(cell: Cell, seed: int, watch: CompileWatch, needed=None):
     """Build the served state and warm up every shape the traffic uses.
     ``needed`` lists the fleet indices whose adapter pages the run will
@@ -205,7 +229,6 @@ def setup(cell: Cell, seed: int, watch: CompileWatch, needed=None):
     import jax
 
     import weights
-    from repro.configs.base import ModelConfig, default_blocks
     from repro.core import LoRAQuantConfig
     from repro.models import build_model
     from repro.serving.engine import AdapterStore, MultiLoRAEngine, Request
@@ -213,11 +236,7 @@ def setup(cell: Cell, seed: int, watch: CompileWatch, needed=None):
     mc, traffic = cell.mc, cell.traffic
     parts = {}
     t = time.perf_counter()
-    fields = {f.name for f in dataclasses.fields(ModelConfig)}
-    model_cfg = ModelConfig(
-        **{k: v for k, v in mc.items() if k in fields and k != "dtype"},
-        dtype=weights.dtype_of(mc), blocks=default_blocks(mc["n_layers"]))
-    model = build_model(model_cfg)
+    model = build_model(model_config(mc))
     base = jax.block_until_ready(weights.base_params(mc, seed))
     parts["init"] = time.perf_counter() - t
 
@@ -445,7 +464,7 @@ def run(args, t_start: float) -> int:
         max_rows=cell.traffic["server"]["max_rows"])
     metrics, computed = {}, []
     for m in metric_entries(cell, bool(args.trace)):
-        value = _module(CODE / "metrics" / f"{m['name']}.py").read(ctx)
+        value = layout.load(CODE / "metrics" / f"{m['name']}.py").read(ctx)
         if value is None:
             log(f"metric {m['name']}: nothing to read")
             continue
@@ -463,7 +482,7 @@ def run(args, t_start: float) -> int:
     gc.collect()
 
     t = time.perf_counter()
-    ref = _module(CODE / "references" / f"{cell.mc['reference']}.py")
+    ref = layout.load(CODE / "references" / f"{cell.mc['reference']}.py")
     res = (ref.logit_gaps(cell.mc, cell.traffic["fleet"]["recipe"],
                           args.seed, seqs, **reference_shape(cell.traffic))
            if seqs else None)

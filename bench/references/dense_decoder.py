@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from counts import LORA_PATHS
+import layout
 from weights import base_params, fleet_codes
 
 HI = jax.lax.Precision.HIGHEST
@@ -124,9 +124,10 @@ def _layer_fn(mc_json: str, mode: str):
         mixer, ffn = sub["mixer"], sub["ffn"]
         norm_at = lambda p: jax.tree_util.tree_map(lambda v: v[layer], p)
         y = _norm(x, norm_at(sub["mixer_norm"]), mc["norm"], eps)
-        q = lin(y, mixer, lora, "wq", layer).reshape(b, t, kvh, h // kvh, dh)
-        k = lin(y, mixer, lora, "wk", layer).reshape(b, t, kvh, dh)
-        v = lin(y, mixer, lora, "wv", layer).reshape(b, t, kvh, dh)
+        la = lora["mixer"]
+        q = lin(y, mixer, la, "wq", layer).reshape(b, t, kvh, h // kvh, dh)
+        k = lin(y, mixer, la, "wk", layer).reshape(b, t, kvh, dh)
+        v = lin(y, mixer, la, "wv", layer).reshape(b, t, kvh, dh)
         q = _rope(q.reshape(b, t, h, dh), mc["rope_theta"]).reshape(q.shape)
         k = _rope(k, mc["rope_theta"])
         s = jnp.einsum("btkgd,bskd->bkgts", q, k, precision=HI) / np.sqrt(dh)
@@ -134,11 +135,12 @@ def _layer_fn(mc_json: str, mode: str):
         s = jnp.where(causal, s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("bkgts,bskd->btkgd", p, v, precision=HI)
-        x = x + lin(o.reshape(b, t, h * dh), mixer, lora, "wo", layer)
+        x = x + lin(o.reshape(b, t, h * dh), mixer, la, "wo", layer)
         y = _norm(x, norm_at(sub["ffn_norm"]), mc["norm"], eps)
-        g = lin(y, ffn, lora, "wg", layer)
-        u = lin(y, ffn, lora, "wu", layer)
-        return x + lin(jax.nn.silu(g) * u, ffn, lora, "wd", layer)
+        lf = lora["ffn"]
+        g = lin(y, ffn, lf, "wg", layer)
+        u = lin(y, ffn, lf, "wu", layer)
+        return x + lin(jax.nn.silu(g) * u, ffn, lf, "wd", layer)
 
     return layer_fn
 
@@ -168,22 +170,27 @@ def logits(mc: dict, base: dict, lora: dict, tokens: np.ndarray,
     if mode == "fp8":
         emb = jax.jit(_f8)(emb)
     x = jnp.take(emb, jnp.asarray(tokens), axis=0)
-    sub = base["groups"][0]["sub_0"]
-    for layer in range(mc["n_layers"]):
-        x = layer_fn(x, sub, lora, jnp.int32(layer))
+    for gi, g in enumerate(layout.groups(mc)):
+        for layer in range(g.count):
+            for sub in g.subs:
+                if (sub.mixer, sub.ffn) != ("attn", "dense"):
+                    raise ValueError(f"the dense reference has no {sub}")
+                x = layer_fn(x, base["groups"][gi][sub.name],
+                             lora["groups"][gi][sub.name], jnp.int32(layer))
     return _logits(x, base["final_norm"], _head(base, mc),
                    jnp.asarray(where), kind=mc["norm"], eps=mc["norm_eps"],
                    mode=mode)
 
 
 def adapters(mc: dict, recipe: dict, seed: int, indices) -> dict:
-    """Dequantized ``{path: {"a": (n, L, r, in), "bt": (n, L, r, out)}}`` of
-    the fleet adapters at ``indices``, one row per sequence."""
+    """The fleet adapters at ``indices``, one row per sequence, dequantized
+    into the program's LoRA tree layout: each linear's leaf is
+    ``{"a": (n, L, r, in), "bt": (n, L, r, out)}``."""
     codes = fleet_codes(mc, recipe, seed, indices)
     bits = recipe["bits_high"]
-    return {p: {"a": dequantize(codes[p], "a", bits),
-                "bt": dequantize(codes[p], "b", bits)}
-            for p in LORA_PATHS}
+    return layout.nest({p: {"a": dequantize(f, "a", bits),
+                            "bt": dequantize(f, "b", bits)}
+                        for p, f in codes.items()})
 
 
 def logit_gaps(mc: dict, recipe: dict, seed: int, seqs, rows: int,

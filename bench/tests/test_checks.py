@@ -14,6 +14,7 @@ import pytest
 
 import faults
 import harness
+import layout
 
 CELL = "olmo1b-chat-zipf"
 
@@ -48,7 +49,7 @@ def test_broken_path_is_not_correct(fault, monkeypatch, capsys):
 
 def test_float8_control_fails_the_limit():
     cell = harness.load_cell(CELL, rehearsal=True)
-    ref = harness._module(harness.CODE / "references" /
+    ref = layout.load(harness.CODE / "references" /
                           f"{cell.mc['reference']}.py")
     rng = np.random.default_rng(0)
     seqs = [(a, rng.integers(0, cell.mc["vocab"], 16).astype(np.int32),

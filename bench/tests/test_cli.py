@@ -5,6 +5,7 @@ With ``BENCH_REHEARSAL=1`` each cell runs end to end at its files'
 without it the command refuses a machine with no TPU, and a checkout with
 no program, printing no result."""
 
+import hashlib
 import json
 import os
 import shutil
@@ -141,6 +142,90 @@ def test_a_cell_added_as_data_only(tmp_path, add):
     assert out["correct"] is True
     assert out["attempted"] > 0 and out["failed"] == 0
     assert set(out["rehearsal"]["computed"]) == e2e
+
+
+def _hashes(root) -> dict:
+    return {str(p.relative_to(root)): hashlib.sha256(p.read_bytes()).digest()
+            for p in root.rglob("*")
+            if p.is_file() and "__pycache__" not in p.parts}
+
+
+def _mla_moe_cell(lora_on_experts: bool):
+    """A model of layers the benchmark has no kind for: one MLA + dense
+    layer, then two MLA + MoE layers, at the program's ``deepseek-v3-671b``
+    smoke sizes, with 8 experts in place of its 4 (the fleet's 1-bit side
+    packs 8 features a byte, and the router's LoRA has one output per
+    expert) and a capacity factor of ``n_experts``, so that no token drops.
+    Neither the MTP head nor int8 expert storage is set."""
+    mc = {"name": "mla-moe", "source": "test", "family": "moe",
+          "n_layers": 3, "d_model": 128, "n_heads": 4, "n_kv_heads": 4,
+          "d_ff": 256, "vocab": 512, "rope_theta": 10000.0,
+          "tie_embeddings": False, "lora_rank": 16, "lora_alpha": 32.0,
+          "dtype": "float32", "norm": "rmsnorm",
+          "blocks": [{"count": 1, "pattern": ["mla"], "ffn": ["dense"]},
+                     {"count": 2, "pattern": ["mla"], "ffn": ["moe"]}],
+          "mla": {"q_lora_rank": 32, "kv_lora_rank": 16, "rope_head_dim": 8,
+                  "nope_head_dim": 16, "v_head_dim": 16},
+          "moe": {"n_experts": 8, "top_k": 2, "d_ff_expert": 64,
+                  "n_shared": 1, "capacity_factor": 8.0,
+                  "lora_on_experts": lora_on_experts}}
+    with open(os.path.join(BENCH, "traffic", "chat-zipf.json")) as f:
+        mix = json.load(f)
+    mix["rehearsal"].update(prompt_tokens=[16], rate_per_s=4.0)
+    mix["rehearsal"]["fleet"]["adapters"] = 4
+    mix["rehearsal"]["server"].update(max_rows=2, hbm_slots=2)
+    return mc, mix
+
+
+@pytest.mark.parametrize("lora_on_experts", [False, True],
+                         ids=["shared-lora", "expert-lora"])
+def test_a_model_of_new_kinds_added_as_files_only(tmp_path, lora_on_experts):
+    """Kinds of layer (``blocks/mla.py``, ``blocks/moe.py``), a
+    configuration that groups them, a mix and a cell, added as new files to
+    a copy of the checkout: the benchmark's trees match the program's
+    ``Model.init``, its fleet matches ``AdapterStore.register``'s layout,
+    and a short window serves every request, with no file of the harness
+    edited."""
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    shutil.copytree(BENCH, tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("out", "__pycache__"))
+    before = _hashes(tmp_path / "bench")
+
+    spec = _cells()
+    mc, mix = _mla_moe_cell(lora_on_experts)
+    cell = "mla-moe-chat"
+    spec["configs"].append({"name": "mla-moe", "source": "test",
+                            "file": "bench/configs/mla-moe.json",
+                            "reduced": [], "why": "test"})
+    spec["workloads"].append({"name": cell, "config": "mla-moe",
+                              "traffic": "chat-short", "chips": 1,
+                              "why": "test"})
+    data = {"configs/mla-moe.json": mc, "traffic/chat-short.json": mix,
+            f"limits/{cell}.json": {"logit_gap": 1.0}}
+    for name, content in data.items():
+        (tmp_path / "bench" / name).write_text(json.dumps(content))
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    for kind in ("mla", "moe"):
+        shutil.copy(os.path.join(BENCH, "tests", "data", "blocks",
+                                 f"{kind}.py"), tmp_path / "bench" / "blocks")
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               BENCH_SPEC=str(tmp_path / "BENCHMARK.json"),
+               PYTHONPATH=os.path.join(ROOT, "src"))
+    proc = subprocess.run(
+        [sys.executable, str(tmp_path / "bench" / "tests" /
+                             "serve_new_kinds.py"), cell, "5", "3"],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=900)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+
+    after = _hashes(tmp_path / "bench")
+    assert {p: after.get(p) for p in before} == before
+    assert set(after) - set(before) == {
+        "blocks/mla.py", "blocks/moe.py", *data}
+    assert out["base"] and out["lora"] and out["fleet"]
+    assert out["requests"] > 0 and out["done"] == out["requests"]
+    assert out["bad"] == 0
 
 
 def test_calibrate_reads_program_control_and_faults():

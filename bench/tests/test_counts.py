@@ -4,17 +4,27 @@ peak table."""
 import pytest
 
 import counts
+import layout
 import peaks
 
 # one tiny decoder: d 8, 2 heads of 4 (1 KV head), d_ff 16, vocab 10, rank 2
 MC = {"n_layers": 3, "d_model": 8, "n_heads": 2, "n_kv_heads": 1,
-      "head_dim": 4, "d_ff": 16, "vocab": 10, "lora_rank": 2}
+      "head_dim": 4, "d_ff": 16, "vocab": 10, "lora_rank": 2,
+      "blocks": [{"count": 3, "pattern": ["attn"], "ffn": ["dense"]}]}
+# the same three layers as a group of one two-sub-block layer and a group of
+# one single
+MC2 = dict(MC, blocks=[
+    {"count": 1, "pattern": ["attn", "attn"], "ffn": ["dense", "dense"]},
+    {"count": 1, "pattern": ["attn"], "ffn": ["dense"]}])
 
 
 def test_path_shapes():
-    assert counts.path_shapes(MC) == {
-        "wq": (8, 8), "wk": (8, 4), "wv": (8, 4), "wo": (8, 8),
-        "wg": (8, 16), "wu": (8, 16), "wd": (16, 8)}
+    names = {"wq": (8, 8), "wk": (8, 4), "wv": (8, 4), "wo": (8, 8),
+             "wg": (8, 16), "wu": (8, 16), "wd": (16, 8)}
+    role = {n: "mixer" if n in ("wq", "wk", "wv", "wo") else "ffn"
+            for n in names}
+    assert {lin.path: (lin.k, lin.m) for lin in layout.linears(MC)} == {
+        f"/groups/0/sub_0/{role[n]}/{n}": s for n, s in names.items()}
 
 
 def test_dense_flops_per_token():
@@ -51,10 +61,26 @@ def test_sgmv_call():
 def test_sgmv_decode_step_sums_paths_and_layers():
     f, b = counts.sgmv_decode_step(MC, 2, 1, 2, 128)
     want_f = want_b = 0
-    for k, m in counts.path_shapes(MC).values():
-        ff, bb = counts.sgmv_call(2, k, m, 2, 2, 128, 1)
+    for lin in layout.linears(MC):
+        ff, bb = counts.sgmv_call(2, lin.k, lin.m, 2, 2, 128, 1)
         want_f, want_b = want_f + ff, want_b + bb
     assert (f, b) == (3 * want_f, 3 * want_b)
+
+
+def test_two_groups_two_subs_count_as_their_layers():
+    """Counts walk every group and sub-block: 21 linears in three layers,
+    each counted once, and the same totals as one group of three."""
+    lins = layout.linears(MC2)
+    assert len(lins) == 21 and {lin.count for lin in lins} == {1}
+    assert [lin.path for lin in lins][6:9] == [
+        "/groups/0/sub_0/ffn/wd", "/groups/0/sub_1/mixer/wq",
+        "/groups/0/sub_1/mixer/wk"]
+    assert counts.dense_flops_per_token(MC2) == 4992 + 160
+    assert counts.attention_flops(MC2, 5) == 480
+    assert counts.decode_step_flops(MC2, 2, 12) == 2 * 5152 + 96 * 12
+    assert counts.prefill_flops(MC2, [3, 1]) == 4 * 5152 + 576 + 96
+    assert counts.sgmv_decode_step(MC2, 2, 1, 2, 128) == \
+        counts.sgmv_decode_step(MC, 2, 1, 2, 128)
 
 
 def test_roofline_names_its_bound():

@@ -7,9 +7,12 @@ is freed, without taking anything the program made.
 * The base is random (``uniform(±1/sqrt(in))`` projections, ``N(0, 0.02²)``
   embeddings, unit norm weights) in the configuration's dtype, laid out as
   the program's parameter tree (``{"groups": [{"sub_0": ...}]}``, layer
-  stacks on a leading axis).
+  stacks on a leading axis). Each kind of layer (``blocks/<kind>.py``) lists
+  its leaves; the random ones draw one key each, across groups, sub-blocks
+  and kinds in declared order, then the embedding and the head.
 * The fleet arrives already quantized, as a restarting server loads it from
-  storage: per adapter, path and layer, LoRAQuant's canonical storage form
+  storage: per adapter, LoRA-targeted linear and layer (and expert, for a
+  per-expert adapter), LoRAQuant's canonical storage form
   at rank ``r`` — a ``bits_high``-bit RTN high side and a 1-bit low side of
   both factors, codes packed little-endian into bytes (code ``i`` of a word
   at bits ``[i·bits, (i+1)·bits)``), a float32 scale per group of ``group``
@@ -27,10 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from counts import LORA_PATHS, path_shapes
+import layout
 
-ATTN_PATHS = ("wq", "wk", "wv", "wo")
-FFN_PATHS = ("wg", "wu", "wd")
 SPECTRUM_DECAY = 0.3      # per rank component, as a trained adapter's
 FACTOR_RMS = 0.02         # RMS entry of a dequantized LoRA factor
 H_RANGE = (1, 4)          # split h drawn per (adapter, path, layer)
@@ -53,25 +54,42 @@ def base_params(mc: dict, seed: int) -> dict:
         jax.random.fold_in(seed_key(seed), 0))
 
 
+def _init(rule: str, keys, shape, dtype):
+    """A leaf by its kind's init rule: ``fan_in`` (a linear ``(..., in,
+    out)``, ``uniform(±1/sqrt(in))``, drawing the next of ``keys``) or
+    ``ones``."""
+    if rule == "fan_in":
+        s = 1.0 / np.sqrt(shape[-2])
+        return jax.random.uniform(next(keys), shape, dtype, -s, s)
+    if rule == "ones":
+        return jnp.ones(shape, dtype)
+    raise ValueError(f"no init rule {rule!r}")
+
+
 def _base(mc, key):
-    n_layers, d, vocab = mc["n_layers"], mc["d_model"], mc["vocab"]
+    d, vocab = mc["d_model"], mc["vocab"]
     dt = dtype_of(mc)
-    shapes = path_shapes(mc)
-    ks = jax.random.split(key, len(shapes) + 2)
-
-    def linear(k, name):
-        i, o = shapes[name]
-        s = 1.0 / np.sqrt(i)
-        return {"w": jax.random.uniform(k, (n_layers, i, o), dt, -s, s)}
-
-    paths = {n: linear(k, n) for n, k in zip(LORA_PATHS, ks)}
     parametric = mc["norm"] != "nonparam_ln"
-    norm = {"w": jnp.ones((n_layers, d), jnp.float32)} if parametric else {}
-    sub = {"mixer": {n: paths[n] for n in ATTN_PATHS}, "mixer_norm": norm,
-           "ffn": {n: paths[n] for n in FFN_PATHS}, "ffn_norm": norm}
-    base = {"groups": [{"sub_0": sub}],
-            "final_norm": ({"w": jnp.ones((d,), jnp.float32)}
-                           if parametric else {})}
+
+    def norm(lead):
+        return {"w": jnp.ones(lead + (d,), jnp.float32)} if parametric else {}
+
+    groups = [{sub.name: {"mixer": {}, "mixer_norm": norm((g.count,)),
+                          "ffn": {}, "ffn_norm": norm((g.count,))}
+               for sub in g.subs} for g in layout.groups(mc)]
+    leaves = [(groups[gi][sub.name][role], g.count, leaf)
+              for gi, g, sub, role, mod in layout.roles(mc)
+              for leaf in mod.base_leaves(mc)]
+    ks = jax.random.split(
+        key, sum(leaf[3] == "fan_in" for *_, leaf in leaves) + 2)
+    drawn = iter(ks)
+    for node, count, (path, shape, dtype, rule) in leaves:
+        *parents, name = path.split("/")
+        for p in parents:
+            node = node.setdefault(p, {})
+        node[name] = _init(rule, drawn, (count,) + tuple(shape),
+                           jnp.dtype(dtype))
+    base = {"groups": groups, "final_norm": norm(())}
     emb = {"e": jax.random.normal(ks[-2], (vocab, d), dt) * 0.02}
     if mc["tie_embeddings"]:
         base["embed_tied"] = emb
@@ -83,25 +101,26 @@ def _base(mc, key):
 
 def lora_template(mc: dict) -> dict:
     """Shapes of one adapter in the program's LoRA tree layout: ``a`` is
-    ``(L, r, in)`` and ``b`` is ``(L, out, r)``, float32."""
-    n_layers, r = mc["n_layers"], mc["lora_rank"]
-
-    def leaf(name):
-        i, o = path_shapes(mc)[name]
-        return {"a": jax.ShapeDtypeStruct((n_layers, r, i), jnp.float32),
-                "b": jax.ShapeDtypeStruct((n_layers, o, r), jnp.float32)}
-
-    return {"groups": [{"sub_0": {"mixer": {n: leaf(n) for n in ATTN_PATHS},
-                                  "ffn": {n: leaf(n) for n in FFN_PATHS}}}]}
+    ``(L, *lead, r, in)`` and ``b`` is ``(L, *lead, out, r)``, float32."""
+    r = mc["lora_rank"]
+    return layout.nest({
+        lin.path: {
+            "a": jax.ShapeDtypeStruct((lin.count,) + lin.lead + (r, lin.k),
+                                      jnp.float32),
+            "b": jax.ShapeDtypeStruct((lin.count,) + lin.lead + (lin.m, r),
+                                      jnp.float32)}
+        for lin in layout.linears(mc)})
 
 
 def fleet_codes(mc: dict, recipe: dict, seed: int,
                 adapters) -> dict:
     """Canonical quantized storage of the adapters with these fleet indices:
-    ``{path: {field: array (n_adapters, L, r, ...)}}`` with fields
-    ``h`` ``(n, L)``; ``ah_codes``/``bh_codes`` ``(n, L, r, G, group·bits/8)``
-    uint8, ``ah_scale``/``bh_scale``/``ah_zero``/``bh_zero`` ``(n, L, r, G)``;
-    ``al_codes``/``bl_codes`` ``(n, L, r, G, group/8)`` and
+    ``{path: {field: array (n_adapters, L, *lead, r, ...)}}``, keyed by the
+    full path of each LoRA-targeted linear (``layout.linears``), with
+    fields ``h`` ``(n, L, *lead)``; ``ah_codes``/``bh_codes``
+    ``(n, L, *lead, r, G, group·bits/8)`` uint8,
+    ``ah_scale``/``bh_scale``/``ah_zero``/``bh_zero`` ``(n, L, *lead, r,
+    G)``; ``al_codes``/``bl_codes`` ``(…, r, G, group/8)`` and
     ``al_scale``/``bl_scale``. ``a*`` fields run over the layer's input
     features, ``b*`` over its outputs (B stored transposed, grouped along
     its output axis). Every row is generated at full rank; row ``j`` of a
@@ -114,24 +133,25 @@ def fleet_codes(mc: dict, recipe: dict, seed: int,
 
 def _adapter(mc, recipe, key, index):
     key = jax.random.fold_in(key, index)
-    n_layers, r = mc["n_layers"], mc["lora_rank"]
+    r = mc["lora_rank"]
     bits, group = recipe["bits_high"], recipe["group_size"]
     decay = jnp.exp(-SPECTRUM_DECAY * jnp.arange(r, dtype=jnp.float32))
     rtn_rms = np.sqrt(np.mean([(c - z) ** 2 for c in range(2 ** bits)
                                for z in (1, 2)]))
     out = {}
-    for pi, name in enumerate(LORA_PATHS):
+    for pi, lin in enumerate(layout.linears(mc)):
         kp = jax.random.fold_in(key, pi)
         ks = jax.random.split(kp, 12)
-        fields = {"h": jax.random.randint(ks[0], (n_layers,), H_RANGE[0],
+        layers = (lin.count,) + lin.lead
+        fields = {"h": jax.random.randint(ks[0], layers, H_RANGE[0],
                                           H_RANGE[1] + 1, jnp.int32)}
-        for si, (side, dim) in enumerate(zip("ab", path_shapes(mc)[name])):
+        for si, (side, dim) in enumerate(zip("ab", (lin.k, lin.m))):
             g = min(group, dim)
             ng = dim // g
-            shape = (n_layers, r, ng)
+            shape = layers + (r, ng)
             jitter = lambda k: jax.random.uniform(k, shape, jnp.float32,
                                                   0.5, 1.5)
-            comp = decay[None, :, None] * FACTOR_RMS
+            comp = decay[:, None] * FACTOR_RMS
             o = 5 * si + 1
             fields[f"{side}h_codes"] = jax.random.bits(
                 ks[o], shape + (g * bits // 8,), jnp.uint8)
@@ -141,5 +161,5 @@ def _adapter(mc, recipe, key, index):
             fields[f"{side}l_codes"] = jax.random.bits(
                 ks[o + 3], shape + (g // 8,), jnp.uint8)
             fields[f"{side}l_scale"] = comp * jitter(ks[o + 4])
-        out[name] = fields
+        out[lin.path] = fields
     return out
