@@ -2,10 +2,18 @@
 profiled sub-window covered. The metric readers share these.
 
 Programs are matched by the XLA module names JAX gives the engine's jitted
-calls. The kernels carry no name of their own yet, so the fused SGMV
-kernel is any Pallas kernel inside the decode program (the only kernel
-there), or an operation named after it once it is named; ``PERF.md`` lists
-the names as a TPU trace shows them.
+calls, as a TPU trace names their runs: ``jit_decode_step(<id>)`` (decode),
+``jit__lambda(<id>)`` (the admission prefill and the cache-row scatter),
+``jit__page_write(<id>)`` (a swap-in). An operation's scope path is the
+``op_name`` of its HLO instruction (``devtrace.load``): the jitted
+functions, ``jax.named_scope``s and control flow that made it, as
+``jit(decode_step)/jit(decode_step)/while/body/closed_call/dot_general``
+for an operation of the decode program's layer loop. The fused SGMV kernel
+has no name of its own in the trace: it is the custom call
+``closed_call.<n>`` (target ``tpu_custom_call``, 112 a decode step), whose
+scope path ends in the ``pallas_call`` of every Pallas kernel. So it is
+any Pallas kernel inside the decode program (the only kernel there), or an
+operation named after it once it is named.
 """
 
 from __future__ import annotations
@@ -34,6 +42,24 @@ def is_sgmv(event) -> bool:
     """Whether a trace event is the fused SGMV kernel (within the decode
     program, where it is the only kernel)."""
     return event[6] or SGMV_OP in event[2]
+
+
+def scope_seconds(ctx, runs, name: str) -> tuple:
+    """``(count, seconds)`` of the device operations inside ``runs`` whose
+    scope path has a component equal to ``name``: a ``jax.named_scope``'s
+    name, or a jitted function's as the trace writes it
+    (``jit(decode_step)``). An operation that runs inside another one that
+    matches (the body of a loop under the scope, inside the loop's own
+    operation) is counted with it and not again."""
+    if ctx.trace is None:
+        return 0, 0.0
+    ops = devtrace.ops_within(ctx.events,
+                              lambda e: name in devtrace.scope(e), runs)
+    n, total, end = 0, 0.0, float("-inf")
+    for start, dur in sorted(ops, key=lambda o: (o[0], -o[1])):
+        if start + dur > end:
+            n, total, end = n + 1, total + dur, start + dur
+    return n, total / 1e9
 
 
 def prefill_runs(ctx) -> list:

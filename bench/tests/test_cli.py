@@ -151,9 +151,9 @@ def _hashes(root) -> dict:
 
 
 def _mla_moe_cell(lora_on_experts: bool):
-    """A model of layers the benchmark has no kind for: one MLA + dense
-    layer, then two MLA + MoE layers, at the program's ``deepseek-v3-671b``
-    smoke sizes, with 8 experts in place of its 4 (the fleet's 1-bit side
+    """A model of MLA and MoE layers: one MLA + dense layer, then two MLA +
+    MoE layers, at the program's ``deepseek-v3-671b`` smoke sizes, with 8
+    experts in place of its 4 (the fleet's 1-bit side
     packs 8 features a byte, and the router's LoRA has one output per
     expert) and a capacity factor of ``n_experts``, so that no token drops.
     Neither the MTP head nor int8 expert storage is set."""
@@ -177,18 +177,49 @@ def _mla_moe_cell(lora_on_experts: bool):
     return mc, mix
 
 
-@pytest.mark.parametrize("lora_on_experts", [False, True],
-                         ids=["shared-lora", "expert-lora"])
-def test_a_model_of_new_kinds_added_as_files_only(tmp_path, lora_on_experts):
+def _add_kinds(blocks, kinds) -> set:
+    """Copy ``tests/data/blocks/<kind>.py`` into the ``blocks`` directory for
+    each of ``kinds`` whose module it does not hold yet, as the PR that adds
+    a model's first layer of a kind writes it; give the copied files' paths
+    relative to ``bench/``."""
+    copied = set()
+    for kind in sorted(kinds):
+        if not (blocks / f"{kind}.py").exists():
+            shutil.copy(os.path.join(BENCH, "tests", "data", "blocks",
+                                     f"{kind}.py"), blocks)
+            copied.add(f"blocks/{kind}.py")
+    return copied
+
+
+def test_add_kinds_copies_only_absent_modules(tmp_path):
+    """A kind whose module the checkout has keeps it; an absent one is
+    copied from the drafts."""
+    (tmp_path / "mla.py").write_text("# the checkout's own\n")
+    assert _add_kinds(tmp_path, {"mla", "moe"}) == {"blocks/moe.py"}
+    assert (tmp_path / "mla.py").read_text() == "# the checkout's own\n"
+    with open(os.path.join(BENCH, "tests", "data", "blocks", "moe.py")) as f:
+        assert (tmp_path / "moe.py").read_text() == f.read()
+    assert _add_kinds(tmp_path, {"mla", "moe"}) == set()
+
+
+@pytest.mark.parametrize("lora_on_experts,present", [
+    (False, ()), (True, ()), (False, ("mla", "moe"))],
+    ids=["shared-lora", "expert-lora", "kinds-present"])
+def test_a_model_of_new_kinds_added_as_files_only(tmp_path, lora_on_experts,
+                                                  present):
     """Kinds of layer (``blocks/mla.py``, ``blocks/moe.py``), a
     configuration that groups them, a mix and a cell, added as new files to
     a copy of the checkout: the benchmark's trees match the program's
     ``Model.init``, its fleet matches ``AdapterStore.register``'s layout,
     and a short window serves every request, with no file of the harness
-    edited."""
+    edited. A kind's module is added only where the checkout has none:
+    ``present`` puts the drafts in the copy first, as a checkout holds
+    them once a model of those kinds is in the benchmark."""
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
     shutil.copytree(BENCH, tmp_path / "bench",
                     ignore=shutil.ignore_patterns("out", "__pycache__"))
+    blocks = tmp_path / "bench" / "blocks"
+    _add_kinds(blocks, present)
     before = _hashes(tmp_path / "bench")
 
     spec = _cells()
@@ -205,9 +236,10 @@ def test_a_model_of_new_kinds_added_as_files_only(tmp_path, lora_on_experts):
     for name, content in data.items():
         (tmp_path / "bench" / name).write_text(json.dumps(content))
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
-    for kind in ("mla", "moe"):
-        shutil.copy(os.path.join(BENCH, "tests", "data", "blocks",
-                                 f"{kind}.py"), tmp_path / "bench" / "blocks")
+    kinds = {k for g in mc["blocks"] for k in g["pattern"] + g["ffn"]}
+    copied = _add_kinds(blocks, kinds)
+    assert copied == {f"blocks/{k}.py" for k in ("mla", "moe")
+                      if k not in present}
 
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                BENCH_SPEC=str(tmp_path / "BENCHMARK.json"),
@@ -221,8 +253,7 @@ def test_a_model_of_new_kinds_added_as_files_only(tmp_path, lora_on_experts):
 
     after = _hashes(tmp_path / "bench")
     assert {p: after.get(p) for p in before} == before
-    assert set(after) - set(before) == {
-        "blocks/mla.py", "blocks/moe.py", *data}
+    assert set(after) - set(before) == {*copied, *data}
     assert out["base"] and out["lora"] and out["fleet"]
     assert out["requests"] > 0 and out["done"] == out["requests"]
     assert out["bad"] == 0
